@@ -20,7 +20,7 @@ import dataclasses
 import math
 import re
 
-from repro.launch.mesh import TPU_V5E
+from repro.launch.mesh import target_hardware
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -112,16 +112,15 @@ class Roofline:
 
 def analyze(compiled, hlo_text: str | None = None) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per device
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()
     coll = parse_collectives(text)
 
-    compute_s = flops / TPU_V5E["peak_bf16_flops"]
-    memory_s = nbytes / TPU_V5E["hbm_bandwidth"]
-    collective_s = coll.total_bytes / TPU_V5E["ici_link_bandwidth"]
+    hw = target_hardware()
+    compute_s = flops / hw["peak_bf16_flops"]
+    memory_s = nbytes / hw["hbm_bandwidth"]
+    collective_s = coll.total_bytes / hw["ici_link_bandwidth"]
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)
@@ -164,18 +163,23 @@ _ELEM_BYTES = 4
 # room for Mosaic's double buffering of grid inputs and spills.
 _VMEM_FRACTION = 0.25
 
-_MIN_BLOCK = 128        # ≥ the f32 min tile's lane count (8, 128)
+# Row blocks are whole (8, 128) tiles of 32-bit rows: the kernels lay a
+# block of ``b`` rows out as a (b / 128, 128) tile, and Mosaic takes a
+# block only when its second-minor dimension is a multiple of 8.
+_LANES = 128
+_MIN_BLOCK = 8 * _LANES
 _MAX_BLOCK = 8192
 
 
 def machine_balance() -> float:
     """Machine balance point in FLOPs/byte: arithmetic intensities above
     it are compute-bound on the MXU, below it HBM-bandwidth-bound."""
-    return TPU_V5E["peak_bf16_flops"] / TPU_V5E["hbm_bandwidth"]
+    hw = target_hardware()
+    return hw["peak_bf16_flops"] / hw["hbm_bandwidth"]
 
 
 def vmem_budget_bytes() -> int:
-    return int(TPU_V5E["vmem_bytes"] * _VMEM_FRACTION)
+    return int(target_hardware()["vmem_bytes"] * _VMEM_FRACTION)
 
 
 def _pow2_floor(n: int) -> int:
@@ -252,18 +256,23 @@ def filter_agg_tiling(*, n_cols: int, n_aggs: int) -> KernelTiling:
                    flops, bpr)
 
 
+def _group_tile_bytes(n_groups: int, n_aggs: int) -> int:
+    """The one-hot kernels' partials: one (K, 128) tile per aggregate plus
+    the presence count, and the (K, 128) one-hot stripe itself."""
+    return (n_aggs + 2) * max(n_groups, 1) * _LANES * _ELEM_BYTES
+
+
 def groupby_tiling(kernel: str, *, n_cols: int, n_aggs: int,
                    n_groups: int) -> KernelTiling:
-    """One-hot grouped aggregation (sum/count matmul on the MXU, plus
-    masked broadcast min/max reductions for ``segmented_minmax``): the
-    (block, K) one-hot tile dominates the working set."""
+    """One-hot grouped aggregation (sums/counts, plus masked min/max for
+    ``segmented_minmax``): every 128-row stripe of a block folds into
+    per-group lane partials, which dominate the working set at large K."""
     K, A = max(n_groups, 1), n_aggs
 
     def ws(b):
         return ((n_cols + 1) * b * _ELEM_BYTES      # input columns + mask
-                + b * K * _ELEM_BYTES               # one-hot matrix
-                + K * (A + 1) * _ELEM_BYTES)        # accumulator tile
-    flops = 2.0 * K * (A + 1)                       # one-hot matmul row
+                + _group_tile_bytes(K, A))
+    flops = 2.0 * K * (A + 1)                       # compare + fold per group
     bpr = float((n_cols + 1) * _ELEM_BYTES)
     block = _grid_block(ws, flops, bpr)
     return _finish(kernel, block, _MAX_BLOCK * 16, ws(block), flops, bpr)
@@ -282,8 +291,7 @@ def join_probe_tiling(*, n_cols: int, n_payload: int, n_aggs: int,
     def ws(b):
         return (resident * build_lane
                 + (n_cols + 1) * b * _ELEM_BYTES
-                + b * K * _ELEM_BYTES
-                + K * (A + 1) * _ELEM_BYTES)
+                + _group_tile_bytes(K, A))
     # log2(B) binary-search compares + gathers + the agg update
     flops = 2.0 * math.log2(max(resident, 2)) + 2.0 * K * (A + 1)
     bpr = float((n_cols + 1) * _ELEM_BYTES)
@@ -343,8 +351,8 @@ def interpret_prefers_jnp(tiling: KernelTiling) -> bool:
 
 def onehot_group_capacity(n_aggs: int = 4) -> int:
     """Largest group domain K the one-hot kernels accept: at the minimum
-    block the (block, K) one-hot plus the (K, A+1) accumulator must fit
-    the VMEM budget. Replaces the hand-picked MAX_KERNEL_GROUPS."""
-    budget = vmem_budget_bytes()
-    lane = (_MIN_BLOCK + n_aggs + 1) * _ELEM_BYTES
-    return _pow2_floor(max(budget // lane, 1))
+    block the input tiles plus the per-group lane partials must fit the
+    VMEM budget."""
+    lane = (n_aggs + 2) * _LANES * _ELEM_BYTES
+    room = vmem_budget_bytes() - 2 * _MIN_BLOCK * _ELEM_BYTES
+    return _pow2_floor(max(room // lane, 1))

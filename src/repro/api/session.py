@@ -32,6 +32,7 @@ import threading
 import time
 from collections import deque
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.cost import CostModel
 from repro.core.engine import (CoordinatorConfig, QueryCancelled,
                                QueryEngine, QueryResult)
@@ -386,4 +387,5 @@ def connect(store: ObjectStore | None = None,
     ``platform``) or the knobs to build them (``store_dir``, ``tier``,
     ``quota``, ``faults``, ``seed``); see :class:`SkyriseSession`.
     """
+    enable_compile_cache()
     return SkyriseSession(store, catalog, **kwargs)

@@ -99,6 +99,11 @@ class PipelineReport:
     # kernel dispatch + metadata-cache visibility (section 3.3/3.4 hot path)
     kernel: str = ""               # fused kernel the plan lowers to
     kernel_fragments: int = 0      # fragments that ran on the fused path
+    # {kernel: fragments} as run (a semi-join probe's Bloom kernel is
+    # attached at run time, past the plan), and how many of those ran
+    # in Pallas interpret mode instead of compiled by Mosaic
+    kernel_runs: dict = dataclasses.field(default_factory=dict)
+    kernel_interpreted: int = 0
     kernel_miss_reason: str = ""   # why the matcher fell back (if it did)
     kernel_roofline: dict | None = None   # roofline-chosen tiling
     footer_cache_hits: int = 0
@@ -1424,6 +1429,10 @@ class QueryEngine:
                         self._accumulate_bloom(report, bw, spec["bloom"])
                     if s.get("kernel"):
                         report.kernel_fragments += 1
+                        report.kernel_runs[s["kernel"]] = \
+                            report.kernel_runs.get(s["kernel"], 0) + 1
+                        if s.get("kernel_mode") == "interpreted":
+                            report.kernel_interpreted += 1
                     if s.get("pipelined"):
                         # consumer-side pipelined read observations:
                         # first byte = earliest fragment's first batch

@@ -62,6 +62,7 @@ def make_worker_handler(store: ObjectStore,
                 "bytes_written": stats.bytes_written,
                 "footer_cache_hits": stats.footer_cache_hits,
                 "kernel": stats.kernel,
+                "kernel_mode": stats.kernel_mode,
                 "tier_ops": stats.tier_ops,
                 "pipelined": stats.pipelined,
                 "first_input_s": stats.first_input_s,

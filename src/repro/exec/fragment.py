@@ -21,6 +21,7 @@ from repro.exec import exchange
 from repro.exec import lower
 from repro.exec import operators as ops
 from repro.kernels import bloom as bloomlib
+from repro.kernels import common as kcommon
 from repro.exec.batch import bucket_capacity, from_numpy, to_numpy
 from repro.exec.expr import expr_from_dict
 from repro.storage import pax
@@ -41,6 +42,7 @@ class FragmentStats:
     bytes_written: int = 0
     footer_cache_hits: int = 0
     kernel: str = ""       # fused Pallas kernel this fragment ran on ("" = jnp)
+    kernel_mode: str = ""  # "compiled" (Mosaic) or "interpreted" (Pallas)
     # Pipelined consumption: this fragment read at least one input from a
     # partial manifest. ``first_input_s`` is the simulated makespan of the
     # first available batch (the fragment's exposed input latency);
@@ -181,8 +183,9 @@ def _build(op: dict, leaves: list[tuple[str, dict]]):
 
 
 def _compiled(op: dict):
-    """(jitted fn, leaves, kernel name, cache key) for an op tree: kernel
-    dispatch first (``repro.exec.lower``), generic jnp chain otherwise."""
+    """(jitted fn, leaves, kernel name, cache key, kernel-at-capacities
+    predicate) for an op tree: kernel dispatch first
+    (``repro.exec.lower``), generic jnp chain otherwise."""
     # read the dispatch switch once: key and lowering gate must agree, or
     # a concurrent toggle could park a generic program under a fused key
     dispatch = lower.enabled()
@@ -205,11 +208,12 @@ def _compiled(op: dict):
         _FN_CACHE_STATS["misses"] += 1
     lowered = lower.lower_fragment(op) if dispatch else None
     if lowered is not None:
-        entry = (jax.jit(lowered.fn), lowered.leaves, lowered.kernel, key)
+        entry = (jax.jit(lowered.fn), lowered.leaves, lowered.kernel, key,
+                 lowered.runs_kernel)
     else:
         leaves: list[tuple[str, dict]] = []
         fn = _build(op, leaves)
-        entry = (jax.jit(fn), leaves, "", key)
+        entry = (jax.jit(fn), leaves, "", key, None)
     with _FN_CACHE_LOCK:
         return _FN_CACHE.setdefault(key, entry)
 
@@ -429,8 +433,7 @@ def execute_fragment(store: ObjectStore, spec: dict,
             and op.get("t") in ("scan_table", "filter", "project"):
         op = {"t": "semijoin_probe", "key": sj["key"][0],
               "bits": int(sj["bits"]), "k": int(sj["k"]), "child": op}
-    fn, leaves, kernel, fn_key = _compiled(op)
-    stats.kernel = kernel
+    fn, leaves, kernel, fn_key, runs_kernel = _compiled(op)
 
     # 1. Load leaf inputs (host side, ranged + pruned + re-triggered reads).
     blocks = {}
@@ -449,6 +452,13 @@ def execute_fragment(store: ObjectStore, spec: dict,
         stats.rows_in += n
         blk = from_numpy(cols, bucket_capacity(n))
         blocks[leaf_id] = (blk.columns, blk.mask)
+
+    # A matched kernel may still take its XLA fallback at these capacities
+    # (past a resident cap): only a kernel that runs is reported.
+    if kernel and runs_kernel(lower.leaf_capacities(blocks)):
+        stats.kernel = kernel
+        stats.kernel_mode = ("interpreted" if kcommon.interpret_mode()
+                             else "compiled")
 
     # 2. Execute the fused XLA program (trace/compile paid untimed, once
     # per op×capacity bucket — simulated runtime is steady-state compute).

@@ -13,14 +13,14 @@ Matched patterns (paper section 3.3's one-pass vectorized worker loop):
   ``scan → [filter…] → partial_agg/merge_agg`` (direct, no groups)
       → :func:`repro.kernels.ops.fused_filter_agg` — predicate and
         aggregate inputs evaluate inside the kernel over VMEM column
-        tiles; one (1, A) accumulator tile crosses the row-block grid.
-        TPC-H Q6 is the canonical instance.
+        tiles; one (8, 128) partial tile per aggregate crosses the
+        row-block grid. TPC-H Q6 is the canonical instance.
 
   ``scan → [filter…] → partial_agg/merge_agg`` (direct, K groups)
-      → :func:`repro.kernels.ops.fused_groupby` when every aggregate is
-        a sum/count (pure one-hot matmul on the MXU, TPC-H Q1), else
-        :func:`~repro.kernels.ops.fused_groupby_minmax`, which adds
-        masked broadcast min/max reductions over the same one-hot tile.
+      → :func:`repro.kernels.ops.fused_groupby` — one-hot masks over
+        (K, 128) partial tiles. Dispatched as ``groupby_onehot`` when
+        every aggregate is a sum/count (TPC-H Q1), else as
+        ``segmented_minmax``, the same kernel with its min/max lanes.
 
   ``scan → [filter…] → partial_agg`` (sort strategy)
       → :func:`repro.kernels.ops.fused_sort_agg` — fully VMEM-resident
@@ -68,7 +68,6 @@ import dataclasses
 import os
 from typing import Callable
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -77,12 +76,8 @@ from repro.exec import operators as xops
 from repro.exec.expr import compile_expr, expr_from_dict
 from repro.exec.operators import decode_group_ids, mixed_radix_strides
 from repro.kernels import ops as kops
+from repro.kernels import common as kcommon
 
-# One-hot grouped aggregation materializes a (block, K) matrix in VMEM;
-# the cap is the largest K whose tile fits the roofline VMEM budget at
-# the minimum block (4096 on v5e — well below the planner's direct-agg
-# strategy bound).
-MAX_KERNEL_GROUPS = roofline.onehot_group_capacity()
 AGG_FNS = frozenset({"sum", "count", "min", "max"})
 ONEHOT_AGG_FNS = frozenset({"sum", "count"})  # pure-matmul groupby subset
 
@@ -162,12 +157,19 @@ class Match:
     bloom_k: int | None = None
 
 
+def _always(caps: dict) -> bool:
+    return True
+
+
 @dataclasses.dataclass
 class Lowered:
     fn: Callable                 # blocks → (columns, mask)
     leaves: list[tuple[str, dict]]
     kernel: str
     tiling: roofline.KernelTiling | None = None
+    # leaf capacities ({leaf id: rows}) → whether ``fn`` runs the kernel
+    # or its identical-semantics XLA fallback at those capacities
+    runs_kernel: Callable[[dict], bool] = _always
 
 
 def _expr_cols(d: dict, out: set) -> None:
@@ -216,10 +218,6 @@ def match_fragment_ex(op: dict) -> tuple[Match | None, str | None]:
     return None, f"no fusible root (op={t})"
 
 
-def _interpret_backend() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _match_final(op: dict):
     sort_keys = [(k, bool(d)) for k, d in (op.get("sort_keys") or [])]
     limit = op.get("limit")
@@ -238,7 +236,7 @@ def _match_final(op: dict):
         return None, f"columns {missing} absent from scan"
     tiling = roofline.resident_sort_tiling(
         "topk", n_arrays=_leaf_width(child, len(sort_keys) + 4) + 2)
-    if _interpret_gate and _interpret_backend() \
+    if _interpret_gate and kcommon.interpret_mode() \
             and roofline.interpret_prefers_jnp(tiling):
         return None, "interpret_cost"
     return Match("topk", child, preds, [], [], [], tiling,
@@ -296,7 +294,7 @@ def _match_agg(op: dict):
             return None, f"columns {missing} absent from scan"
         tiling = roofline.resident_sort_tiling(
             "sort_agg", n_arrays=2 + len(group_cols) + len(aggs))
-        if _interpret_gate and _interpret_backend() \
+        if _interpret_gate and kcommon.interpret_mode() \
                 and roofline.interpret_prefers_jnp(tiling):
             return None, "interpret_cost"
         return Match("sort_agg", child, preds, group_cols, sizes, aggs,
@@ -308,9 +306,11 @@ def _match_agg(op: dict):
         if len(sizes) != len(group_cols):
             return None, "group columns not dict-coded (no sizes)"
         K = int(np.prod(sizes))
-        if K > MAX_KERNEL_GROUPS:
+        # per-group lane partials live in VMEM: the roofline caps K
+        cap = roofline.onehot_group_capacity(len(aggs))
+        if K > cap:
             return None, (f"group domain {K} exceeds the one-hot VMEM cap "
-                          f"{MAX_KERNEL_GROUPS}")
+                          f"{cap}")
     else:
         K = 0
 
@@ -362,9 +362,12 @@ def _match_join(op, join, preds, needed, group_cols, sizes, aggs, K):
         if not bneeded <= set(build_leaf["columns"]):
             missing = sorted(bneeded - set(build_leaf["columns"]))
             return None, f"columns {missing} absent from build scan"
+    # a payload column that is the build key is read from the resident
+    # sorted keys, not held twice
     tiling = roofline.join_probe_tiling(
         n_cols=_leaf_width(probe_leaf, len(needed)),
-        n_payload=len(payload), n_aggs=len(aggs), n_groups=K)
+        n_payload=len(set(payload) - {build_key}), n_aggs=len(aggs),
+        n_groups=K)
     return Match("join_probe_agg", probe_leaf, preds, group_cols, sizes,
                  aggs, tiling, build_leaf=build_leaf,
                  probe_preds=probe_preds, build_preds=build_preds,
@@ -438,8 +441,17 @@ def _compile_pred(preds: list[dict]):
     return pred
 
 
-def _is_pow2(n: int) -> bool:
-    return n > 0 and n & (n - 1) == 0
+def leaf_capacities(blocks: dict) -> dict:
+    """{leaf id: capacity} of a fragment's leaf blocks — static at trace
+    time, so a program's kernel-or-fallback choice is fixed per shape."""
+    return {lid: int(mask.shape[0]) for lid, (_, mask) in blocks.items()}
+
+
+
+def _resident_fits(n: int, cap: int) -> bool:
+    """Capacities the resident bitonic kernels take: a power of two of
+    whole vreg tiles, within the roofline cap."""
+    return kcommon.ROW_TILE <= n <= cap and n & (n - 1) == 0
 
 
 def lower_fragment(op: dict) -> Lowered | None:
@@ -504,13 +516,11 @@ def _lower_direct_agg(m: Match) -> Lowered:
     K = int(np.prod(m.sizes))
     gid_fn = _gid_fn(list(m.group_cols), list(m.sizes))
     group_cols, sizes = list(m.group_cols), list(m.sizes)
-    grouped = (kops.fused_groupby if m.kernel == "groupby_onehot"
-               else kops.fused_groupby_minmax)
 
     def fn(blocks):
         cols, mask = blocks[leaf_id]
-        tile = grouped(cols, mask, pred=pred, gid_fn=gid_fn, aggs=aggs,
-                       n_groups=K, block=block)
+        tile = kops.fused_groupby(cols, mask, pred=pred, gid_fn=gid_fn,
+                                  aggs=aggs, n_groups=K, block=block)
         out = dict(decode_group_ids(group_cols, sizes, K))
         for j, name in enumerate(agg_names):
             out[name] = tile[:, j].astype(jnp.float64)
@@ -524,17 +534,19 @@ def _lower_sort_agg(op: dict, m: Match) -> Lowered:
               if arg is not None else None) for name, fn, arg in m.aggs]
     group_cols = list(m.group_cols)
     # identical-semantics XLA path for capacities the resident bitonic
-    # network can't take (non-power-of-two or past the VMEM cap)
+    # network can't take (see _resident_fits)
     generic = xops.make_sort_agg(
         group_cols, [(n, fn, expr_from_dict(a) if a else None)
                      for n, fn, a in m.aggs])
     cap = m.tiling.resident_rows
     leaf_id = "in0"
 
+    def runs(caps):
+        return _resident_fits(caps[leaf_id], cap)
+
     def fn(blocks):
         cols, mask = blocks[leaf_id]
-        n = int(mask.shape[0])
-        if not _is_pow2(n) or n > cap:
+        if not runs(leaf_capacities(blocks)):
             m2 = mask if pred is None else mask & pred(cols)
             return generic(cols, m2)
         out, om = kops.fused_sort_agg(cols, mask, group_cols=group_cols,
@@ -542,7 +554,7 @@ def _lower_sort_agg(op: dict, m: Match) -> Lowered:
         out = {c: (v.astype(jnp.int64) if c in group_cols
                    else v.astype(jnp.float64)) for c, v in out.items()}
         return out, om
-    return Lowered(fn, [(leaf_id, m.leaf)], m.kernel, m.tiling)
+    return Lowered(fn, [(leaf_id, m.leaf)], m.kernel, m.tiling, runs)
 
 
 def _lower_join_probe(op: dict, m: Match) -> Lowered:
@@ -568,12 +580,15 @@ def _lower_join_probe(op: dict, m: Match) -> Lowered:
         [(n, fn, expr_from_dict(a) if a else None)
          for n, fn, a in m.aggs])
 
+    def runs(caps):
+        return caps["in1"] <= cap
+
     def fn(blocks):
         pcols, pmask = blocks["in0"]
         bcols, bmask = blocks["in1"]
         if build_pred is not None:
             bmask = bmask & build_pred(bcols)
-        if int(bmask.shape[0]) > cap:
+        if not runs(leaf_capacities(blocks)):
             pm = pmask if probe_pred is None else pmask & probe_pred(pcols)
             jcols, jmask = join_generic(pcols, pm, bcols, bmask)
             if agg_pred is not None:
@@ -585,11 +600,13 @@ def _lower_join_probe(op: dict, m: Match) -> Lowered:
         sentinel = jnp.asarray(jnp.iinfo(kdt).max, kdt)
         bk = jnp.where(bmask, bcols[build_key].astype(kdt), sentinel)
         order = jnp.argsort(bk)
-        spay = {c: bcols[c][order] for c in payload if c not in pcols}
+        spay = {c: bcols[c][order] for c in payload
+                if c not in pcols and c != build_key}
         res = kops.fused_join_probe_agg(
             pcols, pmask, bk[order], spay, probe_key=probe_key,
             pred=kernel_pred, gid_fn=gid_fn, aggs=aggs, n_groups=K,
-            block=block)
+            block=block, build_key=(build_key if build_key in payload
+                                    and build_key not in pcols else None))
         if not K:
             out = {name: res[j].reshape(1).astype(jnp.float64)
                    for j, name in enumerate(agg_names)}
@@ -598,7 +615,7 @@ def _lower_join_probe(op: dict, m: Match) -> Lowered:
         for j, name in enumerate(agg_names):
             out[name] = res[:, j].astype(jnp.float64)
         return out, res[:, -1] > 0
-    return Lowered(fn, leaves, m.kernel, m.tiling)
+    return Lowered(fn, leaves, m.kernel, m.tiling, runs)
 
 
 def _lower_bloom_filter(m: Match) -> Lowered:
@@ -616,11 +633,14 @@ def _lower_bloom_filter(m: Match) -> Lowered:
     block = m.tiling.block_rows
     leaf_id = "in0"
 
+    def runs(caps):
+        return caps[leaf_id] > 0
+
     def fn(blocks):
         cols, mask = blocks[leaf_id]
         words = blocks["__bloom"][0]["words"]
         m2 = mask if pred is None else mask & pred(cols)
-        if int(mask.shape[0]) == 0:
+        if not runs(leaf_capacities(blocks)):
             hit = bloom_probe_jnp(cols[key], words, bits=bits, k=k) & m2
         else:
             hit = kops.fused_bloom_filter(
@@ -631,7 +651,7 @@ def _lower_bloom_filter(m: Match) -> Lowered:
         return out, m2
     return Lowered(fn, [(leaf_id, m.leaf),
                         ("__bloom", {"t": "bloom_words"})],
-                   "bloom_filter", m.tiling)
+                   "bloom_filter", m.tiling, runs)
 
 
 def _lower_topk(m: Match) -> Lowered:
@@ -639,12 +659,14 @@ def _lower_topk(m: Match) -> Lowered:
     sort_keys, limit, cap = list(m.sort_keys), m.limit, m.tiling.resident_rows
     leaf_id = "in0"
 
+    def runs(caps):
+        return _resident_fits(caps[leaf_id], cap)
+
     def fn(blocks):
         cols, mask = blocks[leaf_id]
-        n = int(mask.shape[0])
-        if not _is_pow2(n) or n > cap:
+        if not runs(leaf_capacities(blocks)):
             # host sort handles it — pass the filtered batch through
             return cols, (mask if pred is None else mask & pred(cols))
         return kops.fused_topk(cols, mask, pred=pred,
                                sort_keys=sort_keys, limit=limit)
-    return Lowered(fn, [(leaf_id, m.leaf)], m.kernel, m.tiling)
+    return Lowered(fn, [(leaf_id, m.leaf)], m.kernel, m.tiling, runs)

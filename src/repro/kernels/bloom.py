@@ -41,6 +41,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import (LANES, lane_types, resident_spec,
+                                  resident_take, row_block, row_spec,
+                                  to_lanes, to_rows)
+
 BLOOM_K = 6
 BLOOM_BITS_PER_KEY = 12
 BLOOM_MIN_BITS = 1 << 10            # 128 B floor: never degenerate
@@ -197,9 +201,9 @@ def bloom_from_wire(d: dict) -> dict:
 
 # -- fused Pallas probe kernel (exec.lower dispatch target) ----------------------
 
-def _bloom_filter_kernel(*refs, names, key, pred, bits, k, block: int):
+def _bloom_filter_kernel(*refs, names, key, pred, bits, k):
     *col_refs, mask_ref, words_ref, o_ref = refs
-    cols = {n: r[...] for n, r in zip(names, col_refs)}   # (1, block)
+    cols = {n: r[...] for n, r in zip(names, col_refs)}   # (rows, 128)
     m = mask_ref[...] != 0
     if pred is not None:
         m = m & pred(cols)
@@ -207,12 +211,15 @@ def _bloom_filter_kernel(*refs, names, key, pred, bits, k, block: int):
     h1 = _fmix32_jnp(ku ^ jnp.uint32(_SEED1))
     h2 = _fmix32_jnp(ku ^ jnp.uint32(_SEED2)) | jnp.uint32(1)
     bm = jnp.uint32(bits - 1)
-    words = words_ref[...]
+    pos = [(h1 + jnp.uint32(i) * h2) & bm for i in range(k)]
+    (words,) = resident_take(
+        [words_ref], [(p >> jnp.uint32(5)).astype(jnp.int32) for p in pos])
     hit = m
-    for i in range(k):
-        pos = (h1 + jnp.uint32(i) * h2) & bm
-        w = jnp.take(words, (pos >> jnp.uint32(5)).astype(jnp.int32))
-        hit = hit & (((w >> (pos & jnp.uint32(31))) & jnp.uint32(1)) != 0)
+    for p, w in zip(pos, words):
+        # int32 words: the arithmetic shift leaves the tested low bit
+        # identical to the unsigned one
+        hit = hit & (((w >> (p & jnp.uint32(31)).astype(jnp.int32)) & 1)
+                     != 0)
     o_ref[...] = hit.astype(jnp.int32)
 
 
@@ -222,36 +229,33 @@ def fused_bloom_filter(columns: dict, mask, *, pred, key: str, words,
     """One-pass predicate + Bloom membership mask over column blocks.
 
     The filter words stay VMEM-resident across the whole row grid (the
-    size cap keeps them ≤ 512 KiB); each grid step evaluates the
-    compiled predicate closure and the k hash probes over one (1, block)
-    tile and emits the surviving-row mask tile. Returns a bool (n,)
-    mask aligned with the inputs — the caller compacts the columns.
+    size cap keeps them ≤ 512 KiB), laid out ``(words / 128, 128)`` as
+    int32 bit patterns; each grid step evaluates the compiled predicate
+    closure and the k hash probes over one row block (the word fetch
+    walks the resident rows — :func:`~repro.kernels.common.resident_take`)
+    and emits the surviving-row mask tile. Returns a bool (n,) mask
+    aligned with the inputs — the caller compacts the columns.
     """
-    from repro.kernels.common import pad_block
     names = tuple(columns)
     n = mask.shape[0]
     if n == 0:
         return jnp.zeros((0,), dtype=bool)
-    block = min(block, max(n, 8))
-    arrs, m, nb = pad_block([columns[c] for c in names], mask, block)
-    if not interpret:
-        arrs = [a.astype(jnp.float32) if jnp.issubdtype(a.dtype,
-                                                        jnp.floating)
-                else a.astype(jnp.int32) for a in arrs]
-    words = jnp.asarray(words, dtype=jnp.uint32)
-    nw = words.shape[0]
+    block = row_block(n, block)
+    arrs, m, nb = to_rows(to_lanes([columns[c] for c in names], interpret),
+                          mask, block)
+    words = jax.lax.bitcast_convert_type(
+        jnp.asarray(words, dtype=jnp.uint32), jnp.int32)
+    words = jnp.pad(words, (0, (-words.shape[0]) % LANES)).reshape(-1, LANES)
 
-    out = pl.pallas_call(
-        functools.partial(
-            _bloom_filter_kernel, names=names, key=key, pred=pred,
-            bits=bits, k=k, block=block),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))
-                  for _ in range(len(names) + 1)]
-        + [pl.BlockSpec((nw,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.int32),
-        interpret=interpret,
-    )(*[a.reshape(nb, block) for a in arrs],
-      m.astype(jnp.int32).reshape(nb, block), words)
+    with lane_types(interpret):
+        out = pl.pallas_call(
+            functools.partial(_bloom_filter_kernel, names=names, key=key,
+                              pred=pred, bits=bits, k=k),
+            grid=(nb,),
+            in_specs=([row_spec(block)] * (len(names) + 1)
+                      + [resident_spec(words.shape)]),
+            out_specs=row_spec(block),
+            out_shape=jax.ShapeDtypeStruct(m.shape, jnp.int32),
+            interpret=interpret,
+        )(*arrs, m, words)
     return out.reshape(-1)[:n] != 0

@@ -1,22 +1,23 @@
-"""Fused filter + aggregate scan — Pallas TPU kernels.
+"""Fused filter + aggregate scan — Pallas TPU kernel.
 
 The hot loop of a scan-heavy serverless query worker: evaluate a
 conjunctive predicate over columnar blocks and accumulate the aggregates
 in one pass — columns stream HBM→VMEM once, no intermediate mask or
-filtered column is ever materialized. Grid = row blocks; the (1, A)
-result tile accumulates across sequential grid steps.
+filtered column is ever materialized. Grid = row blocks; one (8, 128)
+partial tile per aggregate accumulates across sequential grid steps and
+is reduced to a scalar after the kernel.
 
 Two entry points:
 
-  * :func:`filter_agg` — the Q6-specialized benchmark kernel (fixed
-    predicate shape, sum(price·discount) + count);
   * :func:`fused_filter_agg` — the generic kernel behind the engine's
     dispatch layer (``repro.exec.lower``): predicate and aggregate-input
     expressions are compiled jnp closures evaluated *inside* the kernel
     body over the VMEM-resident column tiles, so any matched
-    scan→filter→partial_agg fragment runs as one streaming pass.
+    scan→filter→partial_agg fragment runs as one streaming pass;
+  * :func:`filter_agg` — TPC-H Q6 (sum(price·discount) + count) written
+    against it, kept as the benchmark's fixed-shape entry point.
 
-In interpret mode (CPU CI) the generic kernel accumulates in float64,
+In interpret mode (CPU CI) the kernel accumulates in float64,
 bit-comparable with the generic jnp operator path; on TPU it runs the
 same program in float32.
 """
@@ -29,106 +30,53 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import NEUTRAL, acc_dtype, pad_block
+from repro.kernels.common import (COMBINE, NEUTRAL, REDUCE, SUBLANES,
+                                  LANES, acc_dtype, fold_rows, lane_types,
+                                  resident_spec, row_block, row_spec,
+                                  to_lanes, to_rows)
 
 BLOCK_ROWS = 2048
-
-
-def _filter_agg_kernel(ship_ref, disc_ref, qty_ref, price_ref, n_ref,
-                       o_ref, *, date_lo, date_hi, disc_lo, disc_hi,
-                       qty_hi, block: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    rows = i * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    ship = ship_ref[...]
-    disc = disc_ref[...]
-    qty = qty_ref[...]
-    price = price_ref[...]
-    mask = ((ship >= date_lo) & (ship < date_hi)
-            & (disc >= disc_lo) & (disc <= disc_hi)
-            & (qty < qty_hi) & (rows < n_ref[0]))
-    zero = jnp.zeros((), jnp.float32)
-    val = jnp.where(mask, price * disc, zero)
-    cnt = mask.astype(jnp.float32)
-    o_ref[0, 0] += jnp.sum(val, dtype=jnp.float32)
-    o_ref[0, 1] += jnp.sum(cnt, dtype=jnp.float32)
 
 
 def filter_agg(shipdate, discount, quantity, extendedprice, *,
                date_lo: int, date_hi: int, disc_lo: float, disc_hi: float,
                qty_hi: float, block: int = BLOCK_ROWS,
                interpret: bool = False) -> jnp.ndarray:
-    """Columns are 1-D f32/i32 arrays of equal length n (padded
-    internally). Returns (2,) f32: [revenue sum, match count]."""
-    n = shipdate.shape[0]
-    block = min(block, max(n, 8))
-    pad = (-n) % block
-    if pad:
-        shipdate = jnp.pad(shipdate, (0, pad))
-        discount = jnp.pad(discount, (0, pad))
-        quantity = jnp.pad(quantity, (0, pad))
-        extendedprice = jnp.pad(extendedprice, (0, pad))
-    nb = (n + pad) // block
+    """Q6 over 1-D columns of equal length n. Returns (2,):
+    [revenue sum, match count]."""
+    cols = {"ship": shipdate, "disc": discount, "qty": quantity,
+            "price": extendedprice}
 
-    def as2d(x, dtype):
-        return x.astype(dtype).reshape(nb, block)
+    def pred(c):
+        return ((c["ship"] >= date_lo) & (c["ship"] < date_hi)
+                & (c["disc"] >= disc_lo) & (c["disc"] <= disc_hi)
+                & (c["qty"] < qty_hi))
 
-    out = pl.pallas_call(
-        functools.partial(
-            _filter_agg_kernel, date_lo=date_lo, date_hi=date_hi,
-            disc_lo=disc_lo, disc_hi=disc_hi, qty_hi=qty_hi, block=block),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 2), jnp.float32),
-        interpret=interpret,
-    )(as2d(shipdate, jnp.int32), as2d(discount, jnp.float32),
-      as2d(quantity, jnp.float32), as2d(extendedprice, jnp.float32),
-      jnp.asarray([n], jnp.int32))
-    return out[0]
+    aggs = [("sum", lambda c: c["price"] * c["disc"]), ("count", None)]
+    return fused_filter_agg(cols, jnp.ones(shipdate.shape, bool),
+                            pred=pred, aggs=aggs, block=block,
+                            interpret=interpret)
 
 
-# -- generic fused filter+aggregate (kernel-dispatch target) -----------------
-
-def _fused_filter_agg_kernel(*refs, names, pred, aggs, acc, block: int):
+def _fused_filter_agg_kernel(*refs, names, pred, aggs, acc):
     *col_refs, mask_ref, o_ref = refs
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
         for j, (fn, _) in enumerate(aggs):
-            if NEUTRAL[fn]:
-                o_ref[0, j] = acc(NEUTRAL[fn])
+            o_ref[j] = jnp.full((SUBLANES, LANES), NEUTRAL[fn], acc)
 
-    cols = {n: r[...] for n, r in zip(names, col_refs)}   # (1, block)
+    cols = {n: r[...] for n, r in zip(names, col_refs)}   # (rows, 128)
     m = mask_ref[...] != 0
     if pred is not None:
         m = m & pred(cols)
     for j, (fn, argf) in enumerate(aggs):
         if fn == "count":
-            o_ref[0, j] += jnp.sum(m.astype(acc))
-            continue
-        v = jnp.broadcast_to(jnp.asarray(argf(cols), acc), m.shape)
-        v = v.astype(acc)
-        if fn == "sum":
-            o_ref[0, j] += jnp.sum(jnp.where(m, v, acc(0)))
-        elif fn == "min":
-            o_ref[0, j] = jnp.minimum(
-                o_ref[0, j], jnp.min(jnp.where(m, v, acc(jnp.inf))))
-        elif fn == "max":
-            o_ref[0, j] = jnp.maximum(
-                o_ref[0, j], jnp.max(jnp.where(m, v, acc(-jnp.inf))))
+            v = m.astype(acc)
+        else:
+            v = jnp.broadcast_to(jnp.asarray(argf(cols), acc), m.shape)
+            v = jnp.where(m, v.astype(acc), acc(NEUTRAL[fn]))
+        o_ref[j] = COMBINE[fn](o_ref[j], fold_rows(v, fn))
 
 
 def fused_filter_agg(columns: dict, mask, *, pred, aggs,
@@ -144,25 +92,18 @@ def fused_filter_agg(columns: dict, mask, *, pred, aggs,
     """
     acc = acc_dtype(interpret)
     names = tuple(columns)
-    n = mask.shape[0]
-    block = min(block, max(n, 8))
-    arrs, m, nb = pad_block([columns[c] for c in names], mask, block)
-    if not interpret:
-        arrs = [a.astype(jnp.float32) if jnp.issubdtype(a.dtype,
-                                                        jnp.floating)
-                else a.astype(jnp.int32) for a in arrs]
-    A = len(aggs)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _fused_filter_agg_kernel, names=names, pred=pred, aggs=aggs,
-            acc=acc, block=block),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))
-                  for _ in range(len(names) + 1)],
-        out_specs=pl.BlockSpec((1, A), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, A), acc),
-        interpret=interpret,
-    )(*[a.reshape(nb, block) for a in arrs],
-      m.astype(jnp.int32).reshape(nb, block))
-    return out[0]
+    block = row_block(mask.shape[0], block)
+    arrs, m, nb = to_rows(to_lanes([columns[c] for c in names], interpret),
+                          mask, block)
+    out_shape = (len(aggs), SUBLANES, LANES)
+    with lane_types(interpret):
+        out = pl.pallas_call(
+            functools.partial(_fused_filter_agg_kernel, names=names,
+                              pred=pred, aggs=aggs, acc=acc),
+            grid=(nb,),
+            in_specs=[row_spec(block)] * (len(names) + 1),
+            out_specs=resident_spec(out_shape),
+            out_shape=jax.ShapeDtypeStruct(out_shape, acc),
+            interpret=interpret,
+        )(*arrs, m)
+    return jnp.stack([REDUCE[fn](out[j]) for j, (fn, _) in enumerate(aggs)])

@@ -10,11 +10,13 @@ in-kernel binary search against it, gathers payload for the hits, applies
 the residual predicates, and folds straight into the aggregation tile —
 the joined relation never leaves VMEM.
 
-The searches and payload gathers use ``jnp.take`` (dynamic gathers on
-Mosaic); a one-hot matmul against the resident build side is the
-MXU-friendly alternative if a target rejects them. Hit semantics mirror
-the generic operator exactly: ``sorted_key[pos] == probe_key``, probe row
-valid, and probe key ≠ the int64 mask sentinel.
+The build side is laid out ``(B / 128, 128)`` like the probe columns.
+Mosaic gathers only along the lanes of one row, so every probe of the
+search and the payload fetch goes through
+:func:`~repro.kernels.common.resident_take`, which walks the build rows.
+Hit semantics mirror the generic operator exactly:
+``sorted_key[pos] == probe_key``, probe row valid, and probe key ≠ the
+mask sentinel.
 """
 
 from __future__ import annotations
@@ -25,132 +27,126 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import (NEUTRAL, acc_dtype, key_dtype,
-                                  pad_block)
-from repro.kernels.segmented_minmax import (grouped_tile_update,
-                                            init_group_tile)
+from repro.kernels.common import (COMBINE, LANES, NEUTRAL, REDUCE,
+                                  SUBLANES, acc_dtype, fold_rows, key_dtype,
+                                  lane_types, resident_spec, resident_take,
+                                  row_block, row_spec, to_lanes, to_rows)
+from repro.kernels.groupby_onehot import (group_tile_shape, init_group_tile,
+                                          reduce_group_tile,
+                                          update_group_tile)
 
 
-def _lower_bound(sk, pk, n_build: int):
-    """Vectorized lower-bound binary search of ``pk`` (block,) in the
-    sorted ``sk`` (B,): first index with sk[i] >= pk, like
+def _lower_bound(sk_ref, pk, n_build: int):
+    """Vectorized lower-bound binary search of ``pk`` (rows, 128) in the
+    sorted resident keys: first index with sk[i] >= pk, like
     ``jnp.searchsorted(side='left')``. Static trip count."""
     lo = jnp.zeros(pk.shape, jnp.int32)
     hi = jnp.full(pk.shape, n_build, jnp.int32)
     for _ in range(max(int(n_build).bit_length(), 1)):
         active = lo < hi
-        mid = (lo + hi) // 2
-        mv = jnp.take(sk, mid)
+        mid = (lo + hi) >> 1                     # (lo + hi) // 2
+        (mv,), = resident_take([sk_ref], [mid])
         go_right = active & (mv < pk)
         lo = jnp.where(go_right, mid + 1, lo)
         hi = jnp.where(active & ~go_right, mid, hi)
     return lo
 
 
-def _join_probe_kernel(*refs, names, bnames, pred, probe_key, gid_fn,
-                       aggs, acc, kdt, n_groups: int, block: int,
-                       n_build: int):
+def _join_probe_kernel(*refs, names, bnames, pred, probe_key, build_key,
+                       gid_fn, aggs, acc, kdt, grouped: bool, n_build: int):
     n_probe_refs = len(names) + 1                 # probe columns + mask
     col_refs = refs[:len(names)]
     mask_ref = refs[len(names)]
     sk_ref = refs[n_probe_refs]
     payload_refs = refs[n_probe_refs + 1:-1]
     o_ref = refs[-1]
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        if n_groups:
-            o_ref[...] = init_group_tile(aggs, n_groups, acc)
+        if grouped:
+            init_group_tile(o_ref, aggs, acc)
         else:
-            o_ref[...] = jnp.zeros_like(o_ref)
             for j, (fn, _) in enumerate(aggs):
-                if NEUTRAL[fn]:
-                    o_ref[0, j] = acc(NEUTRAL[fn])
+                o_ref[j] = jnp.full((SUBLANES, LANES), NEUTRAL[fn], acc)
 
-    cols = {n: r[...][0] for n, r in zip(names, col_refs)}   # (block,)
-    m = mask_ref[...][0] != 0
-    sk = sk_ref[...][0]                                      # (B,)
+    cols = {n: r[...] for n, r in zip(names, col_refs)}   # (rows, 128)
+    m = mask_ref[...] != 0
     sentinel = jnp.asarray(jnp.iinfo(kdt).max, kdt)
     pk = cols[probe_key].astype(kdt)
-    pos = _lower_bound(sk, pk, n_build)
+    pos = _lower_bound(sk_ref, pk, n_build)
     pos_c = jnp.clip(pos, 0, n_build - 1)
-    hit = (jnp.take(sk, pos_c) == pk) & m & (pk != sentinel)
-    for bn, r in zip(bnames, payload_refs):      # gather hits' payload
-        cols[bn] = jnp.take(r[...][0], pos_c)
+    found = resident_take([sk_ref, *payload_refs], [pos_c])
+    hit = (found[0][0] == pk) & m & (pk != sentinel)
+    for bn, (v,) in zip(bnames, found[1:]):      # the hits' payload
+        cols[bn] = v
+    if build_key is not None:                     # equals the key on hits
+        cols[build_key] = found[0][0]
     if pred is not None:
         hit = hit & pred(cols)
 
-    if n_groups:
-        o_ref[...] = grouped_tile_update(o_ref[...], hit, gid_fn(cols),
-                                         cols, aggs, acc, block=block,
-                                         n_groups=n_groups)
+    if grouped:
+        update_group_tile(o_ref, hit, gid_fn(cols), cols, aggs, acc)
         return
     for j, (fn, argf) in enumerate(aggs):
         if fn == "count":
-            o_ref[0, j] += jnp.sum(hit.astype(acc))
-            continue
-        v = jnp.broadcast_to(jnp.asarray(argf(cols), acc), (block,))
-        v = v.astype(acc)
-        if fn == "sum":
-            o_ref[0, j] += jnp.sum(jnp.where(hit, v, acc(0)))
-        elif fn == "min":
-            o_ref[0, j] = jnp.minimum(
-                o_ref[0, j], jnp.min(jnp.where(hit, v, acc(jnp.inf))))
-        elif fn == "max":
-            o_ref[0, j] = jnp.maximum(
-                o_ref[0, j], jnp.max(jnp.where(hit, v, acc(-jnp.inf))))
+            v = hit.astype(acc)
+        else:
+            v = jnp.broadcast_to(jnp.asarray(argf(cols), acc), hit.shape)
+            v = jnp.where(hit, v.astype(acc), acc(NEUTRAL[fn]))
+        o_ref[j] = COMBINE[fn](o_ref[j], fold_rows(v, fn))
 
 
 def fused_join_probe_agg(probe_cols: dict, probe_mask, sorted_keys,
                          sorted_payload: dict, *, probe_key: str, pred,
                          gid_fn, aggs, n_groups: int, block: int,
+                         build_key: str | None = None,
                          interpret: bool = False) -> jnp.ndarray:
     """One-pass join probe + filter + aggregation.
 
     ``sorted_keys``/``sorted_payload`` are the build side already sorted
-    by join key (masked build rows pushed to the end under the int64
-    sentinel — the caller reuses the generic operator's preparation).
-    ``aggs`` fns may be any of {sum, count, min, max}. Returns the (A,)
-    accumulator for ungrouped aggregation (``n_groups == 0``) or the
-    (K, A+1) group tile with trailing presence counts.
+    by join key (masked build rows pushed to the end under the key
+    dtype's sentinel — the caller reuses the generic operator's
+    preparation). ``build_key`` names a payload column that is the join
+    key itself: it is read from the resident keys instead of being held
+    in VMEM twice. ``aggs`` fns may be any of {sum, count, min, max}.
+    Returns the (A,) accumulator for ungrouped aggregation
+    (``n_groups == 0``) or the (K, A+1) group tile with trailing
+    presence counts.
     """
     acc = acc_dtype(interpret)
     kdt = key_dtype(interpret)
     names = tuple(probe_cols)
     bnames = tuple(sorted_payload)
-    n = probe_mask.shape[0]
-    block = min(block, max(n, 8))
-    arrs, mask, nb = pad_block([probe_cols[c] for c in names],
-                               probe_mask, block)
-    sk = sorted_keys.astype(kdt)
-    payload = [sorted_payload[c] for c in bnames]
-    if not interpret:
-        cast = lambda a: (a.astype(jnp.float32)
-                          if jnp.issubdtype(a.dtype, jnp.floating)
-                          else a.astype(jnp.int32))
-        arrs = [cast(a) for a in arrs]
-        payload = [cast(a) for a in payload]
-    B = int(sk.shape[0])
+    block = row_block(probe_mask.shape[0], block)
+    arrs, mask, nb = to_rows(
+        to_lanes([probe_cols[c] for c in names], interpret), probe_mask,
+        block)
+    B = int(sorted_keys.shape[0])
+    # pad the build side to whole 128-lane rows; the search never reads
+    # past index B-1, so the pad value is irrelevant
+    bpad = (-B) % LANES
+    build = [sorted_keys.astype(kdt)] + to_lanes(
+        [sorted_payload[c] for c in bnames], interpret)
+    build = [jnp.pad(a, (0, bpad)).reshape(-1, LANES) for a in build]
     A = len(aggs)
-    out_shape = (n_groups, A + 1) if n_groups else (1, A)
+    grouped = bool(n_groups)
+    shape = (group_tile_shape(aggs, n_groups) if grouped
+             else (A, SUBLANES, LANES))
 
-    out = pl.pallas_call(
-        functools.partial(
-            _join_probe_kernel, names=names, bnames=bnames, pred=pred,
-            probe_key=probe_key, gid_fn=gid_fn, aggs=aggs, acc=acc,
-            kdt=kdt, n_groups=n_groups, block=block, n_build=B),
-        grid=(nb,),
-        in_specs=(
-            [pl.BlockSpec((1, block), lambda i: (i, 0))
-             for _ in range(len(names) + 1)]
-            + [pl.BlockSpec((1, B), lambda i: (0, 0))
-               for _ in range(1 + len(bnames))]),
-        out_specs=pl.BlockSpec(out_shape, lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct(out_shape, acc),
-        interpret=interpret,
-    )(*[a.reshape(nb, block) for a in arrs],
-      mask.astype(jnp.int32).reshape(nb, block),
-      sk.reshape(1, B),
-      *[p.reshape(1, B) for p in payload])
-    return out if n_groups else out[0]
+    with lane_types(interpret):
+        out = pl.pallas_call(
+            functools.partial(
+                _join_probe_kernel, names=names, bnames=bnames, pred=pred,
+                probe_key=probe_key, build_key=build_key, gid_fn=gid_fn,
+                aggs=aggs, acc=acc,
+                kdt=kdt, grouped=grouped, n_build=B),
+            grid=(nb,),
+            in_specs=([row_spec(block)] * (len(names) + 1)
+                      + [resident_spec(b.shape) for b in build]),
+            out_specs=resident_spec(shape),
+            out_shape=jax.ShapeDtypeStruct(shape, acc),
+            interpret=interpret,
+        )(*arrs, mask, *build)
+    if grouped:
+        return reduce_group_tile(out, aggs, n_groups)
+    return jnp.stack([REDUCE[fn](out[j]) for j, (fn, _) in enumerate(aggs)])

@@ -1,9 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the
-kernel body runs as traced jnp on the host, validating semantics; on TPU
-the same call sites compile to Mosaic. ``interpret`` auto-detects the
-backend so call sites never change.
+On a TPU the kernels compile to Mosaic; on any other backend they run in
+Pallas interpret mode — the kernel body runs as traced jnp on the host,
+validating semantics. ``kernels.common.interpret_mode`` decides which,
+so call sites never change.
 """
 
 from __future__ import annotations
@@ -17,14 +17,10 @@ from repro.kernels import filter_agg as _fa
 from repro.kernels import flash_attention as _flash
 from repro.kernels import groupby_onehot as _go
 from repro.kernels import join_probe as _jp
-from repro.kernels import segmented_minmax as _smm
 from repro.kernels import sort_agg as _sa
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import topk as _tk
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels import common
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -34,13 +30,13 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_k: int = _flash.DEFAULT_BLOCK_K):
     return _flash.flash_attention(
         q, k, v, causal=causal, window=window, block_q=block_q,
-        block_k=block_k, interpret=_interpret())
+        block_k=block_k, interpret=common.interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, dt, A_log, B, C, *, chunk: int = 128):
     return _ssd.ssd_scan(x, dt, A_log, B, C, chunk=chunk,
-                         interpret=_interpret())
+                         interpret=common.interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("date_lo", "date_hi", "disc_lo",
@@ -52,14 +48,14 @@ def filter_agg(shipdate, discount, quantity, extendedprice, *,
     return _fa.filter_agg(
         shipdate, discount, quantity, extendedprice, date_lo=date_lo,
         date_hi=date_hi, disc_lo=disc_lo, disc_hi=disc_hi, qty_hi=qty_hi,
-        block=block, interpret=_interpret())
+        block=block, interpret=common.interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("n_groups", "block"))
 def groupby_onehot(group_ids, values, *, n_groups: int,
                    block: int = _go.BLOCK_ROWS):
     return _go.groupby_onehot(group_ids, values, n_groups=n_groups,
-                              block=block, interpret=_interpret())
+                              block=block, interpret=common.interpret_mode())
 
 
 # -- generic fused kernels (engine dispatch targets) --------------------------
@@ -71,52 +67,46 @@ def groupby_onehot(group_ids, values, *, n_groups: int,
 def fused_filter_agg(columns: dict, mask, *, pred, aggs,
                      block: int = _fa.BLOCK_ROWS):
     return _fa.fused_filter_agg(columns, mask, pred=pred, aggs=aggs,
-                                block=block, interpret=_interpret())
+                                block=block, interpret=common.interpret_mode())
 
 
 def fused_groupby(columns: dict, mask, *, pred, gid_fn, aggs,
                   n_groups: int, block: int = _go.BLOCK_ROWS):
     return _go.fused_groupby(columns, mask, pred=pred, gid_fn=gid_fn,
                              aggs=aggs, n_groups=n_groups, block=block,
-                             interpret=_interpret())
-
-
-def fused_groupby_minmax(columns: dict, mask, *, pred, gid_fn, aggs,
-                         n_groups: int, block: int):
-    return _smm.fused_groupby_minmax(
-        columns, mask, pred=pred, gid_fn=gid_fn, aggs=aggs,
-        n_groups=n_groups, block=block, interpret=_interpret())
+                             interpret=common.interpret_mode())
 
 
 def fused_join_probe_agg(probe_cols: dict, probe_mask, sorted_keys,
                          sorted_payload: dict, *, probe_key: str, pred,
-                         gid_fn, aggs, n_groups: int, block: int):
+                         gid_fn, aggs, n_groups: int, block: int,
+                         build_key: str | None = None):
     return _jp.fused_join_probe_agg(
         probe_cols, probe_mask, sorted_keys, sorted_payload,
         probe_key=probe_key, pred=pred, gid_fn=gid_fn, aggs=aggs,
-        n_groups=n_groups, block=block, interpret=_interpret())
+        n_groups=n_groups, block=block, build_key=build_key,
+        interpret=common.interpret_mode())
 
 
 def fused_sort_agg(columns: dict, mask, *, group_cols, pred, aggs):
     return _sa.fused_sort_agg(columns, mask, group_cols=group_cols,
                               pred=pred, aggs=aggs,
-                              interpret=_interpret())
+                              interpret=common.interpret_mode())
 
 
 def fused_bloom_filter(columns: dict, mask, *, pred, key: str, words,
                        bits: int, k: int, block: int):
     return _bloom.fused_bloom_filter(
         columns, mask, pred=pred, key=key, words=words, bits=bits, k=k,
-        block=block, interpret=_interpret())
+        block=block, interpret=common.interpret_mode())
 
 
 def fused_topk(columns: dict, mask, *, pred, sort_keys, limit: int):
     return _tk.fused_topk(columns, mask, pred=pred, sort_keys=sort_keys,
-                          limit=limit, interpret=_interpret())
+                          limit=limit, interpret=common.interpret_mode())
 
 
 def join_key_dtype():
     """Key lane dtype the fused join/sort kernels will use on this
     backend — exposed so the XLA build-side prepass matches."""
-    from repro.kernels.common import key_dtype
-    return key_dtype(_interpret())
+    return common.key_dtype(common.interpret_mode())

@@ -36,9 +36,6 @@ from repro.launch.specs import build_cell
 from repro.models.config import SHAPES
 from repro.parallel import sharding as sh
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-
 
 def _compile_one(cfg, arch, shape_name, mesh, plan, *, remat, donate,
                  scan_layers, optimizer=None, accum_steps=1):

@@ -87,7 +87,8 @@ def run(plan: LNode, tables: dict[str, Table]) -> Table:
         probe = left[plan.left_key]
         pos = np.searchsorted(skeys, probe)
         pos_c = np.clip(pos, 0, max(len(skeys) - 1, 0))
-        hit = (len(skeys) > 0) & (skeys[pos_c] == probe)
+        hit = (skeys[pos_c] == probe) if len(skeys) else \
+            np.zeros(len(probe), bool)
         out = {c: v[hit] for c, v in left.items()}
         sel = order[pos_c[hit]]
         for c, v in right.items():

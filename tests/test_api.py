@@ -233,3 +233,25 @@ def test_failed_query_surfaces_error_and_state():
         assert h.error() is not None
         with pytest.raises(Exception):
             h.result(timeout=10)
+
+
+def test_compile_cache_follows_env_else_checkout_on_tpu(monkeypatch,
+                                                         tmp_path):
+    import jax
+    from repro import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert calls == []                  # JAX's own setting stands
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.enable_compile_cache() is None   # CPU: off
+    assert calls == []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    path = compile_cache.enable_compile_cache()
+    assert path == str(compile_cache.CHECKOUT_CACHE_DIR)
+    assert path.endswith(".jax_cache")
+    assert dict(calls) == {
+        "jax_compilation_cache_dir": path,
+        "jax_persistent_cache_min_compile_time_secs": 0.0}

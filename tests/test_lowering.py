@@ -68,10 +68,13 @@ def test_grouped_minmax_matches_segmented_kernel(tpch_store):
     assert lower.lower_fragment(p.op) is not None
 
 
+_SQL_SORT = ("select l_orderkey, sum(l_quantity) as s, count(*) as c "
+             "from lineitem group by l_orderkey")
+
+
 def test_groupby_nondict_matches_sort_agg(tpch_store):
     store, catalog = tpch_store
-    sql = ("select l_orderkey, sum(l_quantity) as s, count(*) as c "
-           "from lineitem group by l_orderkey")
+    sql = _SQL_SORT
     # On interpreted (non-TPU) backends the compute-bound bitonic sort
     # kernel loses to jnp — dispatch declines with a named reason.
     p = _scan_pipeline(_plan(store, catalog, sql))
@@ -118,6 +121,34 @@ def test_disabled_scope_skips_annotation_and_lowering(tpch_store):
         p = _scan_pipeline(_plan(store, catalog, QUERIES["q6"]))
         assert p.kernel is None
     assert lower.enabled()
+
+
+@pytest.mark.parametrize("sql,final", [(_SQL_SORT, False),
+                                       (QUERIES["q3"], True)])
+def test_resident_kernel_runs_only_within_its_cap(sql, final, tpch_store):
+    """Past the resident cap a sort kernel's program takes its XLA
+    fallback, and the fragment must not report the kernel."""
+    store, catalog = tpch_store
+    with lower.interpret_gate_disabled():
+        plan = _plan(store, catalog, sql)
+        op = next(p.op for p in plan.pipelines.values()
+                  if (p.op["t"] == "final") == final and p.kernel)
+        lowered = lower.lower_fragment(op)
+    cap = lowered.tiling.resident_rows
+    assert lowered.runs_kernel({"in0": cap})
+    assert not lowered.runs_kernel({"in0": 2 * cap})
+    assert not lowered.runs_kernel({"in0": 3 * 1024})   # not a power of 2
+
+
+def test_engine_reports_kernels_that_ran_and_how(tpch_store):
+    store, catalog = tpch_store
+    with connect(store, catalog, config=CFG) as session:
+        res = session.sql(QUERIES["q6"])
+    p = next(p for p in res.stats.pipelines if p.kernel)
+    assert p.kernel_runs == {"filter_agg": p.kernel_fragments}
+    # no TPU here: every kernel that ran was interpreted
+    assert p.kernel_interpreted == p.kernel_fragments > 0
+    assert all(not q.kernel_runs for q in res.stats.pipelines if q is not p)
 
 
 # -- block-level parity across capacity buckets -------------------------------
@@ -284,7 +315,29 @@ def test_roofline_tilings_are_pow2_and_fit_budget():
     again = roofline.filter_agg_tiling(n_cols=6, n_aggs=2)
     assert again == grid[0]
     # the one-hot group cap is the roofline's, not a hand constant
-    assert lower.MAX_KERNEL_GROUPS == roofline.onehot_group_capacity()
+    cap = roofline.onehot_group_capacity(1)
+    op = {"t": "partial_agg", "strategy": "direct", "group_cols": ["g"],
+          "sizes": [2 * cap],
+          "aggs": [["s", "sum", {"t": "col", "name": "v"}]],
+          "child": {"t": "scan_table", "table": "t", "columns": ["g", "v"],
+                    "zone_preds": []}}
+    assert lower.match_fragment(op) is None
+    assert str(cap) in lower.kernel_miss_reason(op)
+
+
+def test_hardware_table_is_keyed_by_device_kind(monkeypatch):
+    from repro.launch import mesh
+
+    class Dev:
+        platform, device_kind = "tpu", "TPU v5 lite"
+    monkeypatch.setattr(mesh.jax, "devices", lambda: [Dev()])
+    assert mesh.target_hardware() is mesh.HARDWARE["TPU v5 lite"]
+    Dev.device_kind = "TPU v99"
+    with pytest.raises(KeyError, match="TPU v99"):
+        mesh.target_hardware()
+    # without a TPU the kernels are tiled for the chip they model
+    Dev.platform = "cpu"
+    assert mesh.target_hardware() is mesh.HARDWARE[mesh.MODELED_KIND]
 
 
 def test_tiling_joins_compiled_cache_key(tpch_store):
