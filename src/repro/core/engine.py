@@ -149,6 +149,14 @@ class PipelineReport:
     # recompute cost of this pipeline alone — the registry's age×cost
     # eviction keep-score
     cost_cents: float = 0.0
+    # host time by span name in ms, summed over every attempt (straggler
+    # duplicates and pilot probes included: that time was spent) and
+    # over concurrent fragments — thread time, which may exceed the
+    # pipeline's wall time. ``fragment.*`` spans come from the workers,
+    # ``coord.*`` from the coordinator waits this pipeline blocked on.
+    # ``counters`` sums the workers' ``decode_ns`` and ``h2d_bytes``.
+    span_ms: dict = dataclasses.field(default_factory=dict)
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -585,7 +593,10 @@ class QueryEngine:
         prefix = f"results/{p.sem_hash}"
         cfg = self.config
         pipelined = cfg.pipelined
+        t0 = time.perf_counter_ns()
         sources = self._resolve_sources(p.op, pipelined=pipelined)
+        if pipelined and sources:
+            self._coord_span(report, p.pid, "coord.await_source", t0)
         partials = [e for e in sources.values() if e.get("partial")]
         if partials:
             report.pipelined = True
@@ -721,7 +732,8 @@ class QueryEngine:
                 run=lambda spec: self._run_fragment(p, spec, report,
                                                     stats,
                                                     extra_fragments),
-                on_all_submitted=on_all_submitted)
+                on_all_submitted=on_all_submitted,
+                on_admit=self._admit_span(report, p.pid))
             completions: dict[int, float] = {
                 f: res.sim_runtime_s for f, res in zip(specs, results)}
 
@@ -742,16 +754,23 @@ class QueryEngine:
                     if t > threshold:
                         self.observer.on_straggler(self.query_id, p.pid,
                                                    f)
+                        t0 = time.perf_counter_ns()
                         self.admission.acquire(1, priority=self.priority,
                                                group=self.tenant)
                         try:
+                            self._coord_span(report, p.pid, "coord.admit",
+                                             t0)
                             # the duplicate's rows/bytes repeat the
                             # original worker's output — bill its cost,
-                            # don't double-count its payload
+                            # don't double-count its payload; its host
+                            # time is real, and spanned
+                            t0 = time.perf_counter_ns()
                             dup = self._invoke(
                                 p, specs[f], report, stats,
                                 attempt=100 + report.attempts,
                                 count_payload=False)
+                            self._coord_span(report, p.pid,
+                                             "coord.straggler_rerun", t0)
                         finally:
                             self.admission.release(1)
                         report.stragglers_retriggered += 1
@@ -952,7 +971,8 @@ class QueryEngine:
             group=self.tenant,
             run=lambda spec: self._run_fragment(p, spec, mreport, stats,
                                                 extra),
-            on_all_submitted=on_all)
+            on_all_submitted=on_all,
+            on_admit=self._admit_span(report, p.pid))
         if pipelined:
             # the wave overlapped the producer tail: fold it into the
             # pipeline's sim window as a concurrent phase starting at
@@ -986,6 +1006,9 @@ class QueryEngine:
         report.exchange_requests += mreport.exchange_requests
         report.footer_cache_hits += mreport.footer_cache_hits
         report.cost_cents += mreport.cost_cents
+        with self._metrics_lock:
+            _add_into(report.span_ms, mreport.span_ms)
+            _add_into(report.counters, mreport.counters)
         if mreport.pipelined:   # wave workers topped up from partial l0
             report.pipelined = True
             report.topups += mreport.topups
@@ -1094,9 +1117,11 @@ class QueryEngine:
                        "schema": p.output_schema},
             "sources": {},
         }
+        t0 = time.perf_counter_ns()
         self.admission.acquire(1, priority=self.priority,
                                group=self.tenant)
         try:
+            self._coord_span(report, p.pid, "coord.admit", t0)
             # attempt=300: outside the fleet's retry (0..2) and
             # straggler-duplicate (100+) attempt ranges, so deterministic
             # fault plans target the probe and the fleet independently
@@ -1176,8 +1201,10 @@ class QueryEngine:
             report.semijoin = dict(sj, applied=False)
             return None
 
+        t0 = time.perf_counter_ns()
         words, build_rows, build_distinct = \
             self._await_build_filter(build_sem)
+        self._coord_span(report, p.pid, "coord.await_filter", t0)
         if words is None:
             report.semijoin = dict(sj, applied=False,
                                    reason="filter unavailable")
@@ -1409,11 +1436,19 @@ class QueryEngine:
                                    fragment=spec["fragment"],
                                    attempt=attempt)
         tier_ops = {}
+        spans = ()
         with self._metrics_lock:
             report.attempts += 1
             if res.payload is not None:
                 s = res.payload["stats"]
                 tier_ops = s["tier_ops"]    # real storage ops: billed
+                spans = s.get("spans", ())
+                for name, t0, t1 in spans:
+                    report.span_ms[name] = report.span_ms.get(name, 0.0) \
+                        + (t1 - t0) / 1e6
+                for k in ("decode_ns", "h2d_bytes"):
+                    report.counters[k] = report.counters.get(k, 0) \
+                        + s.get(k, 0)
                 if count_payload:           # …but a duplicate's output
                     report.rows_out += s["rows_out"]    # repeats rows
                     report.bytes_read += s["bytes_read"]
@@ -1450,7 +1485,28 @@ class QueryEngine:
                                                tier_ops)
             report.cost_cents += cost.total_cents
             stats.cost.merge(cost)
+        for name, t0, t1 in spans:
+            self.observer.on_span(self.query_id, p.pid, spec["fragment"],
+                                  name, t0, t1)
         return res
+
+    def _coord_span(self, report: PipelineReport, pid: int, name: str,
+                    start_ns: int, end_ns: int | None = None) -> None:
+        """Close a coordinator span of pipeline ``pid`` (ending now
+        unless ``end_ns`` is given): summed into the report of the
+        pipeline the wait blocked, and emitted with fragment -1."""
+        if end_ns is None:
+            end_ns = time.perf_counter_ns()
+        with self._metrics_lock:
+            report.span_ms[name] = report.span_ms.get(name, 0.0) \
+                + (end_ns - start_ns) / 1e6
+        self.observer.on_span(self.query_id, pid, -1, name, start_ns,
+                              end_ns)
+
+    def _admit_span(self, report: PipelineReport, pid: int):
+        """``invoke_many``'s admission-wait callback for a pipeline."""
+        return lambda t0, t1: self._coord_span(report, pid, "coord.admit",
+                                               t0, t1)
 
     def _accumulate_bloom(self, report: PipelineReport, words: bytes,
                           bloom_spec: dict) -> None:
@@ -1665,6 +1721,11 @@ class QueryEngine:
         return spec
 
 
+def _add_into(acc: dict, more: dict) -> None:
+    for k, v in more.items():
+        acc[k] = acc.get(k, 0) + v
+
+
 def _stage_order(plan: PhysicalPlan, stage: list[int]) -> list[int]:
     """Same-stage execution order: pipelines that emit a semi-join
     filter (build sides) run before their same-stage probes, so a
@@ -1836,6 +1897,9 @@ def explain_analyze(plan: PhysicalPlan, stats: QueryStats) -> str:
             elif r.kernel_miss_reason:
                 lines.append(
                     f"    kernel: generic jnp — {r.kernel_miss_reason}")
+            if r.span_ms:
+                lines.append("    spans: " + " · ".join(
+                    f"{k} {v:.1f}ms" for k, v in sorted(r.span_ms.items())))
             lines.append("    ops: " + " → ".join(_op_kinds(p.op)[::-1]))
             for a in r.adaptations:
                 lines.append("    adapted: " + _describe_adaptation(a))

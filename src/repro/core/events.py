@@ -42,6 +42,14 @@ class QueryObserver:
         launch (fleet_resize / partition_prune / broadcast_downgrade /
         exchange_retier — see ``repro.core.adaptive``)."""
 
+    def on_span(self, query_id: str, pid: int, fragment: int, name: str,
+                start_ns: int, end_ns: int) -> None:
+        """A host span closed, on ``time.perf_counter_ns``: a fragment
+        layer (``fragment.read`` / ``h2d`` / ``device`` / ``d2h`` /
+        ``write``; every attempt, straggler duplicates included) or a
+        coordinator wait of the pipeline (``coord.*``, ``fragment`` =
+        -1). ``(query_id, pid, fragment)`` names the cause."""
+
 
 class ObserverMux(QueryObserver):
     """Fans events out to many observers; isolates their failures."""
@@ -77,6 +85,10 @@ class ObserverMux(QueryObserver):
 
     def on_adaptation(self, query_id, pid, adaptation):
         self._emit("on_adaptation", query_id, pid, adaptation)
+
+    def on_span(self, query_id, pid, fragment, name, start_ns, end_ns):
+        self._emit("on_span", query_id, pid, fragment, name, start_ns,
+                   end_ns)
 
 
 class ConsoleObserver(QueryObserver):

@@ -346,6 +346,7 @@ class FaasPlatform:
                     run: Callable[[dict], InvocationResult] | None = None,
                     priority: int = 0, group: str | None = None,
                     on_all_submitted: Callable[[], None] | None = None,
+                    on_admit: Callable[[int, int], None] | None = None,
                     ) -> list[InvocationResult]:
         """Run a fleet of fragments concurrently in wall-clock.
 
@@ -367,6 +368,9 @@ class FaasPlatform:
         manifest's ``all_submitted`` flag: consumers admitted after this
         point only wait on work already scheduled ahead of them, which
         keeps partial-input waiting deadlock-free at any quota.
+
+        ``on_admit`` receives the ``time.perf_counter_ns`` start and end
+        of each fragment's wait for its admission slot.
         """
         if run is None:
             def run(spec: dict) -> InvocationResult:
@@ -378,13 +382,17 @@ class FaasPlatform:
             for spec in specs:
                 if cancel_check is not None:
                     cancel_check()
+                t0 = time.perf_counter_ns()
                 self.admission.acquire(1, priority=priority, group=group)
+                t1 = time.perf_counter_ns()
                 try:
                     fut = self.executor.submit(self._run_slot, run, spec)
                 except BaseException:
                     self.admission.release(1)  # slot has no task to free it
                     raise
                 futures.append(fut)
+                if on_admit is not None:
+                    on_admit(t0, t1)
         except BaseException:
             # cancelled (or failed) mid-submission: queued-but-unstarted
             # fragments are cancelled outright (their slot never reaches

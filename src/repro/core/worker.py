@@ -7,6 +7,14 @@ location plus execution statistics used for adaptive behavior and billing.
 One handler is shared by every fragment of a session (the "code package"),
 so the SPAX footer cache it owns is session-scoped: F fragments scanning G
 partitions parse each footer once per object version.
+
+The response's ``stats`` carry the fragment's host spans as ``(name,
+start_ns, end_ns)`` on the worker's ``time.perf_counter_ns``, with the
+``decode_ns`` and ``h2d_bytes`` counters. In a real deployment each
+worker's clock is its own, so only durations compare across workers; in
+this single-process simulation every worker runs on the coordinator's
+host, and the spans share its clock (and a profiler trace's, through one
+anchor).
 """
 
 from __future__ import annotations
@@ -69,6 +77,9 @@ def make_worker_handler(store: ObjectStore,
                 "topups": stats.topups,
                 "overlap_saved_s": stats.overlap_saved_s,
                 "semijoin_killed": stats.semijoin_killed,
+                "spans": stats.spans,
+                "decode_ns": stats.decode_ns,
+                "h2d_bytes": stats.h2d_bytes,
             },
         }
         return response, sim_runtime

@@ -9,6 +9,7 @@ re-execution idempotent: racing duplicate workers overwrite identical bytes.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import threading
@@ -58,6 +59,23 @@ class FragmentStats:
     semijoin_killed: int = 0
     # per-tier request/byte accounting for the cost model
     tier_ops: dict = dataclasses.field(default_factory=dict)
+    # host spans of the fragment's layers as (name, start_ns, end_ns) on
+    # ``time.perf_counter_ns`` — ``fragment.read`` and ``fragment.h2d``
+    # once per leaf, ``fragment.device``, ``fragment.d2h`` and
+    # ``fragment.write`` once — with two counters: host nanoseconds of
+    # decode inside the reads, and bytes ``from_numpy`` handed to the
+    # device (capacity padding and masks included)
+    spans: list = dataclasses.field(default_factory=list)
+    decode_ns: int = 0
+    h2d_bytes: int = 0
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        t0 = time.perf_counter_ns()
+        try:
+            yield
+        finally:
+            self.spans.append((name, t0, time.perf_counter_ns()))
 
     def account(self, tier: str, st: IoStats, *, write: bool) -> None:
         t = self.tier_ops.setdefault(
@@ -70,6 +88,7 @@ class FragmentStats:
             t["bytes_read"] += st.bytes
             self.retriggers += st.retriggers
             self.footer_cache_hits += st.footer_hits
+            self.decode_ns += st.decode_ns
         self.requests += st.requests
         self.bytes_read += 0 if write else st.bytes
         self.bytes_written += st.bytes if write else 0
@@ -442,15 +461,19 @@ def execute_fragment(store: ObjectStore, spec: dict,
             words = np.frombuffer(sj["words"], dtype=np.uint32)
             blocks[leaf_id] = ({"words": words}, np.ones((1,), bool))
             continue
-        if leaf_op["t"] == "scan_table":
-            cols = _load_scan_table(handler_for(None), spec, leaf_op,
-                                    stats)
-        else:
-            cols = _load_scan_exchange(handler_for, store, spec, leaf_op,
-                                       stats)
+        with stats.span("fragment.read"):
+            if leaf_op["t"] == "scan_table":
+                cols = _load_scan_table(handler_for(None), spec, leaf_op,
+                                        stats)
+            else:
+                cols = _load_scan_exchange(handler_for, store, spec,
+                                           leaf_op, stats)
         n = len(next(iter(cols.values()))) if cols else 0
         stats.rows_in += n
-        blk = from_numpy(cols, bucket_capacity(n))
+        with stats.span("fragment.h2d"):
+            blk = from_numpy(cols, bucket_capacity(n))
+        stats.h2d_bytes += blk.mask.nbytes + sum(
+            c.nbytes for c in blk.columns.values())
         blocks[leaf_id] = (blk.columns, blk.mask)
 
     # A matched kernel may still take its XLA fallback at these capacities
@@ -463,12 +486,14 @@ def execute_fragment(store: ObjectStore, spec: dict,
     # 2. Execute the fused XLA program (trace/compile paid untimed, once
     # per op×capacity bucket — simulated runtime is steady-state compute).
     _warm(fn, fn_key, blocks)
-    t0 = time.perf_counter()
-    out_cols, out_mask = fn(blocks)
-    jax.block_until_ready(out_mask)
-    stats.compute_s += time.perf_counter() - t0
+    with stats.span("fragment.device"):
+        out_cols, out_mask = fn(blocks)
+        jax.block_until_ready(out_mask)
+    _, t0, t1 = stats.spans[-1]
+    stats.compute_s += (t1 - t0) / 1e9
     from repro.exec.batch import Block
-    result = to_numpy(Block(dict(out_cols), out_mask))
+    with stats.span("fragment.d2h"):
+        result = to_numpy(Block(dict(out_cols), out_mask))
 
     # 2b. Semi-join kill before partitioning: the fused program emitted a
     # per-row Bloom verdict (``__bloom_pass``), or — for multi-column /
@@ -503,6 +528,7 @@ def execute_fragment(store: ObjectStore, spec: dict,
             result = {c: v[:fop["limit"]] for c, v in result.items()}
 
     # 4. Write deterministic output object(s).
+    t_write = time.perf_counter_ns()
     schema = [pax.ColumnSpec(s["name"], s["kind"], s["dtype"])
               for s in spec["output"]["schema"]]
     names = [s.name for s in schema]
@@ -532,6 +558,7 @@ def execute_fragment(store: ObjectStore, spec: dict,
         out_keys.append(key)
         part_stats.append({"rows": n_out, "bytes": st.bytes, "kmv": [],
                            "write_s": st.sim_time_s})
+    stats.spans.append(("fragment.write", t_write, time.perf_counter_ns()))
 
     # 5. Build-side Bloom filter: fold this fragment's join-key column
     # into fleet-uniform filter words (size fixed by the coordinator so

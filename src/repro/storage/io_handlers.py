@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from typing import Sequence
 
 import numpy as np
@@ -48,6 +49,9 @@ class IoStats:
     footer_hits: int = 0             # footer served from the shared cache
     coalesced_chunks: int = 0        # chunk fetches merged into ranged GETs
     hedges: int = 0                  # cost-model-priced duplicate GETs
+    # host nanoseconds spent decoding: chunk decompression and the
+    # per-column concatenation of row groups (perf_counter_ns)
+    decode_ns: int = 0
 
     def merge(self, other: "IoStats") -> None:
         self.requests += other.requests
@@ -59,6 +63,7 @@ class IoStats:
         self.footer_hits += other.footer_hits
         self.coalesced_chunks += other.coalesced_chunks
         self.hedges += other.hedges
+        self.decode_ns += other.decode_ns
 
 
 @dataclasses.dataclass
@@ -272,6 +277,7 @@ class InputHandler:
                                                         self.coalesce_gap):
             data = self._get(key, (off, length), stats, lat)
             stats.coalesced_chunks += len(members) - 1
+            t0 = time.perf_counter_ns()
             for m in members:
                 spec = footer.spec(m.column)
                 meta = footer.row_groups[m.group].chunks[m.column]
@@ -279,7 +285,9 @@ class InputHandler:
                     spec, meta.raw_len,
                     data[m.off - off:m.off - off + m.length],
                     footer.codec)
+            stats.decode_ns += time.perf_counter_ns() - t0
 
+        t0 = time.perf_counter_ns()
         out = {}
         for n in names:
             spec = footer.spec(n)
@@ -288,6 +296,7 @@ class InputHandler:
                 out[n] = np.concatenate(parts)
             else:
                 out[n] = np.empty((0,), dtype=spec.np_dtype())
+        stats.decode_ns += time.perf_counter_ns() - t0
         return out, footer
 
 
