@@ -154,7 +154,8 @@ class PipelineReport:
     # over concurrent fragments — thread time, which may exceed the
     # pipeline's wall time. ``fragment.*`` spans come from the workers,
     # ``coord.*`` from the coordinator waits this pipeline blocked on.
-    # ``counters`` sums the workers' ``decode_ns`` and ``h2d_bytes``.
+    # ``counters`` sums the workers' ``decode_ns``, ``h2d_bytes`` and
+    # ``d2h_bytes``.
     span_ms: dict = dataclasses.field(default_factory=dict)
     counters: dict = dataclasses.field(default_factory=dict)
 
@@ -1446,7 +1447,7 @@ class QueryEngine:
                 for name, t0, t1 in spans:
                     report.span_ms[name] = report.span_ms.get(name, 0.0) \
                         + (t1 - t0) / 1e6
-                for k in ("decode_ns", "h2d_bytes"):
+                for k in ("decode_ns", "h2d_bytes", "d2h_bytes"):
                     report.counters[k] = report.counters.get(k, 0) \
                         + s.get(k, 0)
                 if count_payload:           # …but a duplicate's output

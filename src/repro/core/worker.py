@@ -10,11 +10,11 @@ partitions parse each footer once per object version.
 
 The response's ``stats`` carry the fragment's host spans as ``(name,
 start_ns, end_ns)`` on the worker's ``time.perf_counter_ns``, with the
-``decode_ns`` and ``h2d_bytes`` counters. In a real deployment each
-worker's clock is its own, so only durations compare across workers; in
-this single-process simulation every worker runs on the coordinator's
-host, and the spans share its clock (and a profiler trace's, through one
-anchor).
+``decode_ns``, ``h2d_bytes`` and ``d2h_bytes`` counters. In a real
+deployment each worker's clock is its own, so only durations compare
+across workers; in this single-process simulation every worker runs on
+the coordinator's host, and the spans share its clock (and a profiler
+trace's, through one anchor).
 """
 
 from __future__ import annotations
@@ -80,6 +80,7 @@ def make_worker_handler(store: ObjectStore,
                 "spans": stats.spans,
                 "decode_ns": stats.decode_ns,
                 "h2d_bytes": stats.h2d_bytes,
+                "d2h_bytes": stats.d2h_bytes,
             },
         }
         return response, sim_runtime

@@ -3,22 +3,28 @@
 XLA programs need static shapes; SQL produces data-dependent cardinalities.
 The execution layer therefore works on ``Block``s: equal-length column
 arrays padded to a bucketed capacity plus a boolean validity mask. Filters
-flip mask bits; joins and aggregations emit capacity-bounded outputs; rows
-are compacted back to numpy only at fragment output boundaries.
+flip mask bits; joins and aggregations emit capacity-bounded outputs. At a
+fragment's output the program itself moves the valid rows to the front
+(``compact``), so the host pulls back only a bucketed prefix of each column,
+all columns in one batched transfer (``to_numpy``).
 
 Capacity bucketing (next power of two, floor 1024) bounds the number of
-distinct shapes XLA compiles per operator.
+distinct shapes XLA compiles per operator, and per output prefix.
 """
 
 from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+MIN_CAPACITY = 1024
 
 
-def bucket_capacity(n: int, floor: int = 1024) -> int:
+def bucket_capacity(n: int, floor: int = MIN_CAPACITY) -> int:
     cap = floor
     while cap < n:
         cap *= 2
@@ -28,11 +34,19 @@ def bucket_capacity(n: int, floor: int = 1024) -> int:
 @dataclasses.dataclass
 class Block:
     columns: dict[str, jnp.ndarray]
-    mask: jnp.ndarray                 # bool[capacity]
+    mask: jnp.ndarray | None = None   # bool[capacity], on an input
+    # on a compacted output instead of ``mask``: its valid rows are the
+    # first ``count`` rows of every column
+    count: int | None = None
+    # bytes ``to_numpy`` pulled from the device for this block
+    pulled_bytes: int = 0
 
     @property
     def capacity(self) -> int:
-        return int(self.mask.shape[0])
+        if self.mask is not None:
+            return int(self.mask.shape[0])
+        return int(next(iter(self.columns.values())).shape[0]) \
+            if self.columns else 0
 
     def col(self, name: str) -> jnp.ndarray:
         return self.columns[name]
@@ -51,8 +65,66 @@ def from_numpy(columns: dict[str, np.ndarray],
     return Block(cols, jnp.asarray(mask))
 
 
+def _ahead(x, s: int):
+    """``x`` moved ``s`` rows towards the front, zeros behind."""
+    return jnp.concatenate([x[s:], jnp.zeros((s,), x.dtype)])
+
+
+def _behind(x, s: int):
+    return jnp.concatenate([jnp.zeros((s,), x.dtype), x[:-s]])
+
+
+def compact(columns: dict[str, jnp.ndarray], mask: jnp.ndarray):
+    """(columns, int32 count of valid rows): a program's output as it
+    leaves the device, the valid rows first in their order. Traceable,
+    for use inside ``jit``.
+
+    Each valid row moves towards the front by the number of invalid rows
+    before it, one binary digit of that distance per step, lowest first:
+    rows keep their order and never land on each other. Every step is a
+    slice and a select, and the prefix count a doubling scan of the same
+    kind. A gather or scatter over the column runs slowly on the TPU, and
+    a sort or ``cumsum`` over it takes seconds to compile there; a select
+    moves values with their bits.
+    """
+    cap = mask.shape[0]
+    upto = mask.astype(jnp.int32)
+    s = 1
+    while s < cap:
+        upto = upto + _behind(upto, s)
+        s *= 2
+    gap = jnp.where(mask, lax.iota(jnp.int32, cap) + 1 - upto, 0)
+    live, cols = mask, dict(columns)
+    s = 1
+    while s < cap:
+        move = live & ((gap & s) != 0)
+        arrive = _ahead(move, s)
+        cols = {n: jnp.where(arrive, _ahead(c, s), c)
+                for n, c in cols.items()}
+        gap = jnp.where(arrive, _ahead(gap, s), gap)
+        live = arrive | (live & ~move)
+        s *= 2
+    return cols, jnp.sum(mask, dtype=jnp.int32)
+
+
+# one program per (columns' shapes, prefix bucket): at most one per power
+# of two below the capacity
+_prefix = jax.jit(lambda columns, rows: {name: col[:rows]
+                                         for name, col in columns.items()},
+                  static_argnums=1)
+
+
 def to_numpy(block: Block) -> dict[str, np.ndarray]:
-    """Compact valid rows back to numpy (host-side, at fragment edges)."""
-    mask = np.asarray(block.mask)
-    return {name: np.asarray(col)[mask]
-            for name, col in block.columns.items()}
+    """A compacted block's valid rows as numpy columns (at fragment edges).
+
+    One batched transfer of every column's prefix up to the count's
+    bucket, or of the whole columns where that bucket reaches the
+    capacity; the host keeps the first ``count`` rows and records the
+    bytes it pulled in ``block.pulled_bytes``."""
+    cols = block.columns
+    rows = bucket_capacity(block.count)
+    if rows < block.capacity:
+        cols = _prefix(cols, rows)
+    pulled = jax.device_get(cols)
+    block.pulled_bytes = sum(v.nbytes for v in pulled.values())
+    return {name: v[:block.count] for name, v in pulled.items()}

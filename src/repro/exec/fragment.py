@@ -16,6 +16,7 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.exec import exchange
@@ -23,7 +24,8 @@ from repro.exec import lower
 from repro.exec import operators as ops
 from repro.kernels import bloom as bloomlib
 from repro.kernels import common as kcommon
-from repro.exec.batch import bucket_capacity, from_numpy, to_numpy
+from repro.exec.batch import (Block, bucket_capacity, compact, from_numpy,
+                              to_numpy)
 from repro.exec.expr import expr_from_dict
 from repro.storage import pax
 from repro.storage.io_handlers import (FooterCache, InputHandler, IoStats,
@@ -62,12 +64,15 @@ class FragmentStats:
     # host spans of the fragment's layers as (name, start_ns, end_ns) on
     # ``time.perf_counter_ns`` — ``fragment.read`` and ``fragment.h2d``
     # once per leaf, ``fragment.device``, ``fragment.d2h`` and
-    # ``fragment.write`` once — with two counters: host nanoseconds of
-    # decode inside the reads, and bytes ``from_numpy`` handed to the
-    # device (capacity padding and masks included)
+    # ``fragment.write`` once — with three counters: host nanoseconds of
+    # decode inside the reads, bytes ``from_numpy`` handed to the device
+    # (capacity padding and masks included), and bytes pulled back from
+    # it (the output's two scalars, then its compacted columns' bucketed
+    # prefix)
     spans: list = dataclasses.field(default_factory=list)
     decode_ns: int = 0
     h2d_bytes: int = 0
+    d2h_bytes: int = 0
 
     @contextlib.contextmanager
     def span(self, name: str):
@@ -201,10 +206,30 @@ def _build(op: dict, leaves: list[tuple[str, dict]]):
     raise TypeError(t)
 
 
+def _compacting(fn):
+    """``fn`` (blocks → columns, mask) as a program whose output leaves
+    the device as ``batch.compact`` shapes it: (columns with the valid
+    rows first, int32 count of them, int32 rows the Bloom verdict
+    killed). A ``__bloom_pass`` verdict column is folded into the mask
+    here, as the host once did, and never leaves the device."""
+    def run(blocks):
+        cols, mask = fn(blocks)
+        cols = dict(cols)
+        killed = jnp.int32(0)
+        if "__bloom_pass" in cols:
+            live = mask & (cols.pop("__bloom_pass") != 0)
+            killed = jnp.sum(mask, dtype=jnp.int32) \
+                - jnp.sum(live, dtype=jnp.int32)
+            mask = live
+        return (*compact(cols, mask), killed)
+    return run
+
+
 def _compiled(op: dict):
     """(jitted fn, leaves, kernel name, cache key, kernel-at-capacities
     predicate) for an op tree: kernel dispatch first
-    (``repro.exec.lower``), generic jnp chain otherwise."""
+    (``repro.exec.lower``), generic jnp chain otherwise. The program
+    returns its output compacted (``_compacting``)."""
     # read the dispatch switch once: key and lowering gate must agree, or
     # a concurrent toggle could park a generic program under a fused key
     dispatch = lower.enabled()
@@ -227,12 +252,12 @@ def _compiled(op: dict):
         _FN_CACHE_STATS["misses"] += 1
     lowered = lower.lower_fragment(op) if dispatch else None
     if lowered is not None:
-        entry = (jax.jit(lowered.fn), lowered.leaves, lowered.kernel, key,
-                 lowered.runs_kernel)
+        entry = (jax.jit(_compacting(lowered.fn)), lowered.leaves,
+                 lowered.kernel, key, lowered.runs_kernel)
     else:
         leaves: list[tuple[str, dict]] = []
         fn = _build(op, leaves)
-        entry = (jax.jit(fn), leaves, "", key, None)
+        entry = (jax.jit(_compacting(fn)), leaves, "", key, None)
     with _FN_CACHE_LOCK:
         return _FN_CACHE.setdefault(key, entry)
 
@@ -252,7 +277,7 @@ def _warm(fn, key, blocks) -> None:
     with _FN_CACHE_LOCK:
         if sig in _WARM_SHAPES:
             return
-    jax.block_until_ready(fn(blocks)[1])
+    jax.block_until_ready(fn(blocks))
     with _FN_CACHE_LOCK:
         _WARM_SHAPES.add(sig)
 
@@ -448,8 +473,10 @@ def execute_fragment(store: ObjectStore, spec: dict,
     # kernel or jnp fallback); other key shapes kill on the host below.
     op = spec["op"]
     sj = spec.get("semijoin")
-    if sj is not None and sj.get("mode") == "u32" and len(sj["key"]) == 1 \
-            and op.get("t") in ("scan_table", "filter", "project"):
+    fused_sj = sj is not None and sj.get("mode") == "u32" \
+        and len(sj["key"]) == 1 \
+        and op.get("t") in ("scan_table", "filter", "project")
+    if fused_sj:
         op = {"t": "semijoin_probe", "key": sj["key"][0],
               "bits": int(sj["bits"]), "k": int(sj["k"]), "child": op}
     fn, leaves, kernel, fn_key, runs_kernel = _compiled(op)
@@ -485,26 +512,26 @@ def execute_fragment(store: ObjectStore, spec: dict,
 
     # 2. Execute the fused XLA program (trace/compile paid untimed, once
     # per op×capacity bucket — simulated runtime is steady-state compute).
+    # It ends at the count of valid rows; then the output comes back in
+    # one batched transfer, only the bucketed prefix of compacted columns.
     _warm(fn, fn_key, blocks)
     with stats.span("fragment.device"):
-        out_cols, out_mask = fn(blocks)
-        jax.block_until_ready(out_mask)
+        out_cols, count, killed = fn(blocks)
+        valid, n_killed = jax.device_get((count, killed))
     _, t0, t1 = stats.spans[-1]
     stats.compute_s += (t1 - t0) / 1e9
-    from repro.exec.batch import Block
+    out = Block(out_cols, count=int(valid))
     with stats.span("fragment.d2h"):
-        result = to_numpy(Block(dict(out_cols), out_mask))
+        result = to_numpy(out)
+    stats.d2h_bytes += valid.nbytes + n_killed.nbytes + out.pulled_bytes
 
-    # 2b. Semi-join kill before partitioning: the fused program emitted a
-    # per-row Bloom verdict (``__bloom_pass``), or — for multi-column /
+    # 2b. Semi-join kill before partitioning: the fused program folded its
+    # per-row Bloom verdict into the compaction, or — for multi-column /
     # non-integer keys — the host probes the filter directly. Either way
     # the count is exact against the predicate-surviving stream, and the
     # killed rows never reach the exchange write.
-    if "__bloom_pass" in result:
-        hit = result.pop("__bloom_pass") != 0
-        stats.semijoin_killed = int(hit.size - hit.sum())
-        if stats.semijoin_killed:
-            result = {c: v[hit] for c, v in result.items()}
+    if fused_sj:
+        stats.semijoin_killed = int(n_killed)
     elif sj is not None and all(c in result for c in sj["key"]):
         filt = bloomlib.bloom_from_wire(sj)
         ku = bloomlib.keys_u32(result, list(sj["key"]), filt["mode"])

@@ -623,8 +623,9 @@ def _lower_bloom_filter(m: Match) -> Lowered:
 
     The program keeps the generic mask semantics (predicate-surviving
     rows stay valid) and emits the Bloom verdict as the reserved
-    ``__bloom_pass`` column, so the fragment driver can count killed
-    rows exactly and compact before partitioning. The jnp fallback
+    ``__bloom_pass`` column, which the fragment program's output step
+    (``exec.fragment._compacting``) folds into the mask on the device,
+    counting killed rows exactly before partitioning. The jnp fallback
     (``exec.fragment._build``'s ``semijoin_probe`` arm) produces the
     same column bit-for-bit — both paths share one hash family."""
     from repro.kernels.bloom import bloom_probe_jnp

@@ -46,25 +46,32 @@ def _session(store, catalog, *, platform=None, cfg=CFG):
 
 @pytest.fixture
 def fragment_runs(monkeypatch):
-    """Every fragment executed, with its own host interval and the
-    capacities and arrays ``from_numpy`` was handed inside it."""
+    """Every fragment executed, with its own host interval, the
+    capacities and arrays ``from_numpy`` was handed inside it, and the
+    output block ``to_numpy`` pulled back."""
     runs, local = [], threading.local()
     run_fragment, from_numpy = worker.execute_fragment, fragment.from_numpy
+    to_numpy = fragment.to_numpy
 
     def timed(store, spec, **kw):
-        local.blocks = []
+        local.blocks, local.out = [], None
         t0 = time.perf_counter_ns()
         res = run_fragment(store, spec, **kw)
         runs.append((spec, t0, time.perf_counter_ns(), res,
-                     local.blocks))
+                     local.blocks, local.out))
         return res
 
     def seen(columns, capacity=None):
         local.blocks.append((columns, capacity))
         return from_numpy(columns, capacity)
 
+    def pulled(block):
+        local.out = block
+        return to_numpy(block)
+
     monkeypatch.setattr(worker, "execute_fragment", timed)
     monkeypatch.setattr(fragment, "from_numpy", seen)
+    monkeypatch.setattr(fragment, "to_numpy", pulled)
     return runs
 
 
@@ -77,7 +84,7 @@ def test_fragment_spans_lie_inside_the_fragment_and_apart(
     runs = [r for r in fragment_runs
             if r[0]["op"]["t"] != "merge_exchange"]
     assert runs
-    for spec, t0, t1, res, blocks in runs:
+    for spec, t0, t1, res, blocks, out in runs:
         st = res.stats
         names = [n for n, _, _ in st.spans]
         assert set(names) == set(FRAGMENT_SPANS)
@@ -97,11 +104,17 @@ def test_fragment_spans_lie_inside_the_fragment_and_apart(
                               for v in cols.values()) + 1)
                    for cols, cap in blocks)
         assert st.h2d_bytes == want
+        # the two int32 scalars and the output as it was pulled: never
+        # more than its columns at capacity, nor fewer than its rows
+        row_bytes = sum(c.dtype.itemsize for c in out.columns.values())
+        assert st.d2h_bytes == 8 + out.pulled_bytes
+        assert out.count * row_bytes <= out.pulled_bytes \
+            <= out.capacity * row_bytes
         # one clock reading serves the device span and compute_s
         (s, e), = [(s, e) for n, s, e in st.spans
                    if n == "fragment.device"]
         assert st.compute_s == pytest.approx((e - s) / 1e9)
-    assert any(res.stats.decode_ns > 0 for _, _, _, res, _ in runs)
+    assert any(res.stats.decode_ns > 0 for _, _, _, res, _, _ in runs)
 
 
 def test_observer_receives_every_span_and_the_report_sums_them(tpch_store):
@@ -128,6 +141,7 @@ def test_observer_receives_every_span_and_the_report_sums_them(tpch_store):
             assert ms == pytest.approx(sums[name])
         assert set(FRAGMENT_SPANS) <= set(report.span_ms)
         assert report.counters["h2d_bytes"] > 0
+        assert report.counters["d2h_bytes"] > 0
     # pipelined consumers waited on their sources; the scans admitted
     names = {e[3] for e in mine}
     assert {"coord.admit", "coord.await_source"} <= names

@@ -3,9 +3,10 @@
 Interpret mode cannot see what Mosaic refuses: block shapes off the
 (8, 128) tiling, scalar stores to VMEM, gathers it has no lowering for.
 Each case plans a real query, lowers its fused fragment program, and
-compiles it ahead of time for one chip of a ``v5e:2x2`` topology at the
-capacities a deployment hands a worker: 2^20-row streaming inputs, and
-the roofline's resident caps for the build side and the sort kernels.
+compiles it as the fragment runs it (its output compacted) ahead of time
+for one chip of a ``v5e:2x2`` topology at the capacities a deployment
+hands a worker: 2^20-row streaming inputs, and the roofline's resident
+caps for the build side and the sort kernels.
 
 The topology is described inside a fixture (never at import), so every
 pytest-xdist worker collects the same tests and only the one running
@@ -23,7 +24,7 @@ import numpy as np
 import pytest
 
 from repro.core import CoordinatorConfig, FaasPlatform, QueryCoordinator
-from repro.exec import lower
+from repro.exec import fragment, lower
 from repro.kernels import bloom
 from repro.kernels import common
 from repro.sql.physical import PlannerConfig
@@ -131,6 +132,11 @@ def test_dispatch_kernel_compiles_for_v5e(kernel, tpch_store, one_chip,
                 else STREAM_ROWS)
         blocks[leaf_id] = _leaf_shapes(plan, catalog, leaf_op, rows,
                                        one_chip)
-    compiled = jax.jit(lowered.fn).lower(blocks).compile()
+    # the program as the fragment runs it: its output compacted
+    compiled = jax.jit(fragment._compacting(lowered.fn)).lower(
+        blocks).compile()
+    text = compiled.as_text()
     # the fused kernel is in the program, not the XLA fallback
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the compaction is slices and selects: no emulated 64-bit loop
+    assert " while(" not in text, kernel
