@@ -154,10 +154,13 @@ class PipelineReport:
     # over concurrent fragments — thread time, which may exceed the
     # pipeline's wall time. ``fragment.*`` spans come from the workers,
     # ``coord.*`` from the coordinator waits this pipeline blocked on.
-    # ``counters`` sums the workers' ``decode_ns``, ``h2d_bytes`` and
-    # ``d2h_bytes``.
+    # ``counters`` sums the workers' ``decode_ns``, ``h2d_bytes``,
+    # ``d2h_bytes``, and ``agg_rows_in`` and ``agg_groups_out`` where
+    # the program aggregates; ``ops`` names the pipeline's operators,
+    # root first, so a reader can tell partial from merge aggregation.
     span_ms: dict = dataclasses.field(default_factory=dict)
     counters: dict = dataclasses.field(default_factory=dict)
+    ops: tuple = ()
 
 
 @dataclasses.dataclass
@@ -544,6 +547,7 @@ class QueryEngine:
 
     def _run_pipeline(self, p: Pipeline, stats: QueryStats) -> PipelineReport:
         report = PipelineReport(p.pid, p.sem_hash, p.n_fragments,
+                                ops=tuple(_op_kinds(p.op)),
                                 kernel=p.kernel or "",
                                 kernel_miss_reason=p.kernel_miss_reason
                                 or "",
@@ -1447,7 +1451,8 @@ class QueryEngine:
                 for name, t0, t1 in spans:
                     report.span_ms[name] = report.span_ms.get(name, 0.0) \
                         + (t1 - t0) / 1e6
-                for k in ("decode_ns", "h2d_bytes", "d2h_bytes"):
+                for k in ("decode_ns", "h2d_bytes", "d2h_bytes",
+                          "agg_rows_in", "agg_groups_out"):
                     report.counters[k] = report.counters.get(k, 0) \
                         + s.get(k, 0)
                 if count_payload:           # …but a duplicate's output

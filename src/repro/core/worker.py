@@ -10,7 +10,8 @@ partitions parse each footer once per object version.
 
 The response's ``stats`` carry the fragment's host spans as ``(name,
 start_ns, end_ns)`` on the worker's ``time.perf_counter_ns``, with the
-``decode_ns``, ``h2d_bytes`` and ``d2h_bytes`` counters. In a real
+``decode_ns``, ``h2d_bytes``, ``d2h_bytes``, ``agg_rows_in`` and
+``agg_groups_out`` counters. In a real
 deployment each worker's clock is its own, so only durations compare
 across workers; in this single-process simulation every worker runs on
 the coordinator's host, and the spans share its clock (and a profiler
@@ -81,6 +82,8 @@ def make_worker_handler(store: ObjectStore,
                 "decode_ns": stats.decode_ns,
                 "h2d_bytes": stats.h2d_bytes,
                 "d2h_bytes": stats.d2h_bytes,
+                "agg_rows_in": stats.agg_rows_in,
+                "agg_groups_out": stats.agg_groups_out,
             },
         }
         return response, sim_runtime

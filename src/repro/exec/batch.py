@@ -10,6 +10,11 @@ all columns in one batched transfer (``to_numpy``).
 
 Capacity bucketing (next power of two, floor 1024) bounds the number of
 distinct shapes XLA compiles per operator, and per output prefix.
+
+A fixed-width byte-string column (``|S<w>``) crosses the device as
+ceil(w/4) int32 word lanes named ``<column>#<w>.<i>``: ``from_numpy``
+splits it, ``to_numpy`` puts it back together, and in between every
+operator moves the lanes like any other column (``lanes_of``).
 """
 
 from __future__ import annotations
@@ -52,12 +57,71 @@ class Block:
         return self.columns[name]
 
 
+def _lane_name(name: str, width: int, i: int) -> str:
+    return f"{name}#{width}.{i}"
+
+
+def lanes_of(columns, name: str) -> list[str]:
+    """The device columns that carry column ``name``: itself, or the word
+    lanes of a byte string, in order."""
+    if name in columns:
+        return [name]
+    head = f"{name}#"
+    lanes = [c for c in columns if c.startswith(head)]
+    if not lanes:
+        raise KeyError(name)
+    return sorted(lanes, key=lambda c: int(c.rsplit(".", 1)[1]))
+
+
+def renamed_lanes(lanes: list[str], name: str) -> list[str]:
+    """``lanes`` (as ``lanes_of`` gives them) under column name ``name``."""
+    return [name if "#" not in c else f"{name}#{c.rsplit('#', 1)[1]}"
+            for c in lanes]
+
+
+def _split_bytes(name: str, arr: np.ndarray) -> dict[str, np.ndarray]:
+    width = arr.dtype.itemsize
+    words = -(-width // 4)
+    buf = np.zeros((len(arr), 4 * words), np.uint8)
+    buf[:, :width] = np.frombuffer(arr.tobytes(), np.uint8).reshape(
+        len(arr), width)
+    lanes = buf.view("<i4")
+    return {_lane_name(name, width, i): np.ascontiguousarray(lanes[:, i])
+            for i in range(words)}
+
+
+def _join_bytes(columns: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
+    """Every group of word lanes in ``columns`` back as its byte string,
+    in the place of its first lane."""
+    out: dict[str, np.ndarray] = {}
+    for c, v in columns.items():
+        if "#" not in c:
+            out[c] = v
+            continue
+        name, rest = c.rsplit("#", 1)
+        if name in out:
+            continue
+        width = int(rest.split(".", 1)[0])
+        lanes = np.stack([columns[x].astype("<i4")
+                          for x in lanes_of(columns, name)], axis=1)
+        raw = np.ascontiguousarray(lanes).view(np.uint8)
+        out[name] = np.ascontiguousarray(raw[:, :width]).view(
+            f"S{width}").reshape(len(v))
+    return out
+
+
 def from_numpy(columns: dict[str, np.ndarray],
                capacity: int | None = None) -> Block:
     n = len(next(iter(columns.values()))) if columns else 0
     cap = capacity if capacity is not None else bucket_capacity(n)
-    cols = {}
+    split = {}
     for name, arr in columns.items():
+        if arr.dtype.kind == "S":
+            split.update(_split_bytes(name, arr))
+        else:
+            split[name] = arr
+    cols = {}
+    for name, arr in split.items():
         pad = np.zeros((cap - n,) + arr.shape[1:], dtype=arr.dtype)
         cols[name] = jnp.asarray(np.concatenate([arr, pad]))
     mask = np.zeros(cap, dtype=bool)
@@ -127,4 +191,5 @@ def to_numpy(block: Block) -> dict[str, np.ndarray]:
         cols = _prefix(cols, rows)
     pulled = jax.device_get(cols)
     block.pulled_bytes = sum(v.nbytes for v in pulled.values())
-    return {name: v[:block.count] for name, v in pulled.items()}
+    return _join_bytes({name: v[:block.count]
+                        for name, v in pulled.items()})

@@ -73,6 +73,11 @@ class FragmentStats:
     decode_ns: int = 0
     h2d_bytes: int = 0
     d2h_bytes: int = 0
+    # a fragment whose program aggregates (partial or merge): the rows it
+    # took in, and the groups its aggregation handed on (the compacted
+    # output's valid-row count, past any HAVING)
+    agg_rows_in: int = 0
+    agg_groups_out: int = 0
 
     @contextlib.contextmanager
     def span(self, name: str):
@@ -280,6 +285,11 @@ def _warm(fn, key, blocks) -> None:
     jax.block_until_ready(fn(blocks))
     with _FN_CACHE_LOCK:
         _WARM_SHAPES.add(sig)
+
+
+def _aggregates(op: dict) -> bool:
+    return op["t"] in ("partial_agg", "merge_agg") or any(
+        _aggregates(op[k]) for k in ("child", "probe", "build") if k in op)
 
 
 # -- input loading ----------------------------------------------------------------
@@ -524,6 +534,9 @@ def execute_fragment(store: ObjectStore, spec: dict,
     with stats.span("fragment.d2h"):
         result = to_numpy(out)
     stats.d2h_bytes += valid.nbytes + n_killed.nbytes + out.pulled_bytes
+    if _aggregates(spec["op"]):
+        stats.agg_rows_in += stats.rows_in
+        stats.agg_groups_out += int(valid)
 
     # 2b. Semi-join kill before partitioning: the fused program folded its
     # per-row Bloom verdict into the compaction, or — for multi-column /

@@ -285,6 +285,9 @@ def _match_agg(op: dict):
         _expr_cols(p, needed)
 
     if strategy == "sort":
+        if op.get("wide_keys"):
+            return None, (f"float or byte-string group keys "
+                          f"{op['wide_keys']} (32-bit key lanes)")
         if child.get("t") not in _LEAF_OPS:
             return None, (f"sort-strategy aggregate over non-scan child "
                           f"(op={child.get('t')})")

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.exec.batch import lanes_of, renamed_lanes
 from repro.exec.expr import compile_expr
 from repro.kernels.common import NEUTRAL
 from repro.sql import ast
@@ -71,11 +72,17 @@ def make_filter(pred: ast.Expr):
 
 
 def make_project(exprs: list[tuple[str, ast.Expr]]):
-    fns = [(name, compile_expr(e)) for name, e in exprs]
+    fns = [(name, e, compile_expr(e)) for name, e in exprs]
 
     def op(cols: Cols, mask):
         out = {}
-        for name, f in fns:
+        for name, e, f in fns:
+            if isinstance(e, ast.Col) and e.name not in cols:
+                # a byte string: its word lanes, under the new name
+                lanes = lanes_of(cols, e.name)
+                out.update(zip(renamed_lanes(lanes, name),
+                               (cols[c] for c in lanes)))
+                continue
             v = f(cols)
             if not hasattr(v, "shape") or v.shape != mask.shape:
                 v = jnp.broadcast_to(jnp.asarray(v), mask.shape)
@@ -165,6 +172,8 @@ def make_sort_agg(group_cols: list[str],
 
     Output capacity equals input capacity (#groups ≤ #rows); invalid rows
     sort last via a leading invalid flag and produce masked-out segments.
+    Integer keys group as int64, float keys as themselves, and a byte
+    string by its word lanes.
     """
     agg_fns = [(name, fn, compile_expr(arg) if arg is not None else None)
                for name, fn, arg in aggs]
@@ -172,7 +181,9 @@ def make_sort_agg(group_cols: list[str],
     def op(cols: Cols, mask):
         n = mask.shape[0]
         inv = (~mask).astype(jnp.int32)
-        keys = [cols[c].astype(jnp.int64) for c in group_cols]
+        key_cols = [c for g in group_cols for c in lanes_of(cols, g)]
+        keys = [cols[c] if jnp.issubdtype(cols[c].dtype, jnp.floating)
+                else cols[c].astype(jnp.int64) for c in key_cols]
         vals = []
         for name, fn, argf in agg_fns:
             if fn == "count":
@@ -195,9 +206,11 @@ def make_sort_agg(group_cols: list[str],
         flags = jnp.concatenate([jnp.ones((1,), bool), diff])
         seg = jnp.cumsum(flags) - 1
         out: Cols = {}
-        for c, k in zip(group_cols, s_keys):
-            out[c] = jax.ops.segment_min(
-                jnp.where(s_mask, k, INT64_SENTINEL), seg, num_segments=n)
+        for c, k in zip(key_cols, s_keys):
+            top = jnp.inf if jnp.issubdtype(k.dtype, jnp.floating) \
+                else INT64_SENTINEL
+            out[c] = jax.ops.segment_min(jnp.where(s_mask, k, top), seg,
+                                         num_segments=n)
         maskf = s_mask.astype(jnp.float64)
         for (name, fn, _), v in zip(agg_fns, s_vals):
             if fn in ("sum", "count"):
@@ -246,8 +259,9 @@ def make_pk_join_probe(probe_key: str, build_key: str,
         sel = order[pos_c]
         out = dict(probe_cols)
         for c in payload_cols:
-            if c not in out:
-                out[c] = build_cols[c][sel]
+            for lane in lanes_of(build_cols, c):
+                if lane not in out:
+                    out[lane] = build_cols[lane][sel]
         return out, hit
     return op
 

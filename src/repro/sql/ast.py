@@ -121,6 +121,19 @@ class InList(Expr):
 
 
 @dataclasses.dataclass(frozen=True)
+class InSubquery(Expr):
+    """``term IN (select ...)``; the binder decorrelates it into a join."""
+    term: Expr
+    query: "SelectStmt"
+
+    def key(self):
+        return ("in_select", self.term.key(), repr(self.query))
+
+    def children(self):
+        return (self.term,)
+
+
+@dataclasses.dataclass(frozen=True)
 class Between(Expr):
     term: Expr
     lo: Expr
@@ -186,6 +199,7 @@ class SelectStmt:
     group_by: tuple[Expr, ...]
     order_by: tuple[OrderItem, ...]
     limit: int | None
+    having: Expr | None = None
 
 
 def walk(e: Expr):
@@ -226,6 +240,8 @@ def map_expr(e: Expr, fn) -> Expr:
     elif isinstance(e, InList):
         e = InList(map_expr(e.term, fn),
                    tuple(map_expr(v, fn) for v in e.values))
+    elif isinstance(e, InSubquery):
+        e = InSubquery(map_expr(e.term, fn), e.query)
     elif isinstance(e, Between):
         e = Between(map_expr(e.term, fn), map_expr(e.lo, fn),
                     map_expr(e.hi, fn))

@@ -10,6 +10,11 @@ TPC-H-scoped simplifications (documented in DESIGN.md): equi-joins must be
 FK→PK (the build side's key is its primary key — true for every TPC-H join
 we target), no NULL semantics (TPC-H data has no NULLs), group-by keys are
 plain columns.
+
+An uncorrelated ``x IN (select k from ... group by k [having ...])`` is
+decorrelated into an inner join: ``k`` is the subquery's only group key,
+so it is unique and the derived relation is the PK side of an ordinary
+``LJoin``, attached to the scan of ``x``'s table before join ordering.
 """
 
 from __future__ import annotations
@@ -181,7 +186,10 @@ class Binder:
         return {c.name: ColType(c.kind, c.dtype, c.dictionary)
                 for c in meta.schema}
 
-    def bind(self, stmt: ast.SelectStmt) -> tuple[LNode, Schema]:
+    def bind(self, stmt: ast.SelectStmt,
+             outer: Schema | None = None) -> tuple[LNode, Schema]:
+        """Bind ``stmt``; ``outer`` is the enclosing query's columns when
+        ``stmt`` is an IN subquery (referencing one is a correlation)."""
         tables = list(stmt.tables) + [j.table for j in stmt.joins]
         for t in tables:
             self.catalog.table(t)  # existence check
@@ -196,15 +204,24 @@ class Binder:
         env: Schema = {}
         for t in tables:
             env.update(schemas[t])
+        if outer is not None:
+            _check_uncorrelated(stmt, env, outer)
 
         def fold(e: ast.Expr) -> ast.Expr:
             return map_fold(e, env)
 
-        # Split WHERE into join edges and filters.
+        # Split WHERE into join edges, IN subqueries and filters.
         where = fold(stmt.where) if stmt.where is not None else None
         join_edges: list[tuple[str, str, str, str]] = []
         filters: list[ast.Expr] = []
+        derived: list[tuple[str, LNode, str]] = []
         for c in ast.conjuncts(where):
+            if isinstance(c, ast.InSubquery):
+                derived.append(self._bind_in_subquery(c, env, len(derived)))
+                continue
+            if any(isinstance(n, ast.InSubquery) for n in ast.walk(c)):
+                raise BindError("IN (select ...) is supported only as a "
+                                "conjunct of WHERE, not under OR or NOT")
             edge = self._as_join_edge(c, col_home)
             if edge is not None:
                 join_edges.append(edge)
@@ -217,7 +234,7 @@ class Binder:
             join_edges.append(edge)
 
         plan = self._plan_joins(tables, schemas, col_home, join_edges,
-                                filters)
+                                filters, derived)
 
         # Aggregation / projection.
         group_cols = []
@@ -243,8 +260,12 @@ class Binder:
                              if a.key() not in
                              [x.key() for x in agg_terms])
 
+        having = fold(stmt.having) if stmt.having is not None else None
+        if having is not None and not (agg_terms or group_cols
+                                       or ast.collect_aggs(having)):
+            raise BindError("HAVING needs GROUP BY or an aggregate")
         out_schema: Schema = {}
-        if agg_terms or group_cols:
+        if agg_terms or group_cols or having is not None:
             # avg → sum/count decomposition for distributed merging
             phys_aggs: list[tuple[str, str, ast.Expr | None]] = []
 
@@ -261,11 +282,16 @@ class Binder:
                     else n)
 
             final_exprs = [replace_aggs(e) for e in out_exprs]
+            having = replace_aggs(having) if having is not None else None
             plan = LAggregate(plan, tuple(group_cols), tuple(phys_aggs))
             agg_env: Schema = {c: env[c] for c in group_cols}
             for name, fn, arg in phys_aggs:
                 agg_env[name] = ColType("num", "<f8" if fn != "count"
                                         else "<i8")
+            if having is not None:
+                # HAVING sees the group keys and the aggregates only
+                _expr_type(having, agg_env)
+                plan = LFilter(plan, having)
             out_schema = {}
             exprs = []
             for name, e in zip(out_names, final_exprs):
@@ -290,6 +316,39 @@ class Binder:
             plan = LLimit(plan, stmt.limit)
         return plan, out_schema
 
+    # .. IN subqueries ..
+    def _bind_in_subquery(self, e: ast.InSubquery, env: Schema,
+                          i: int) -> tuple[str, LNode, str]:
+        """(outer column, derived relation, its key column) of an
+        uncorrelated ``x IN (select k from ... group by k ...)``: the
+        subquery's plan with its one output renamed apart from the outer
+        query's columns. ``k`` must be the only group key, so each value
+        appears once and the inner join keeps the outer rows as a
+        semi-join would."""
+        q = e.query
+        if not isinstance(e.term, ast.Col) or e.term.name not in env:
+            raise BindError("IN (select ...) needs a column of the outer "
+                            "query on its left")
+        if len(q.items) != 1 or not isinstance(q.items[0].expr, ast.Col):
+            raise BindError("IN (select ...) must select one column")
+        key = q.items[0].expr.name
+        if [g for g in q.group_by] != [ast.Col(key)]:
+            raise BindError(
+                f"IN (select {key} ...) over a non-grouped key: {key} must "
+                "be the subquery's only GROUP BY column, so that it is "
+                "unique")
+        if q.order_by or q.limit is not None:
+            raise BindError("ORDER BY / LIMIT in an IN subquery")
+        sub, sub_schema = self.bind(q, outer=env)
+        for ct in (env[e.term.name], sub_schema[q.items[0].alias or key]):
+            if ct.kind != "num":
+                raise BindError("IN (select ...) joins numeric keys only: "
+                                f"{e.term.name} IN (select {key} ...)")
+        name = f"_in{i}_{key}"
+        assert isinstance(sub, LProject)
+        return e.term.name, LProject(sub.child, ((name, ast.Col(key)),)), \
+            name
+
     # .. join graph ..
     def _as_join_edge(self, e: ast.Expr, col_home: dict[str, str]):
         if (isinstance(e, ast.Cmp) and e.op == "="
@@ -302,7 +361,8 @@ class Binder:
                 return (lt, e.left.name, rt, e.right.name)
         return None
 
-    def _plan_joins(self, tables, schemas, col_home, join_edges, filters):
+    def _plan_joins(self, tables, schemas, col_home, join_edges, filters,
+                    derived=()):
         # Per-table filter pushdown happens here (pre-optimizer) simply by
         # attaching filters to their home scan; the rule optimizer handles
         # the general (post-join) case.
@@ -321,6 +381,11 @@ class Binder:
             pred = ast.make_and(table_filters[t])
             if pred is not None:
                 node = LFilter(node, pred)
+            # an IN subquery cuts its column's table before that table
+            # meets any other
+            for col, rel, key in derived:
+                if col_home[col] == t:
+                    node = LJoin(node, rel, col, key)
             return node
 
         if len(tables) == 1:
@@ -361,6 +426,20 @@ class Binder:
         if pred is not None:
             plan = LFilter(plan, pred)
         return plan
+
+
+def _check_uncorrelated(stmt: ast.SelectStmt, env: Schema,
+                        outer: Schema) -> None:
+    exprs = [i.expr for i in stmt.items] + list(stmt.group_by) + \
+        [e for e in (stmt.where, stmt.having) if e is not None] + \
+        [j.on for j in stmt.joins]
+    for e in exprs:
+        for n in ast.walk(e):
+            if isinstance(n, ast.Col) and n.name not in env \
+                    and n.name in outer:
+                raise BindError(
+                    f"correlated subquery: IN (select ...) references the "
+                    f"outer column {n.name}")
 
 
 def _intern(phys_aggs: list, fn: str, arg: ast.Expr | None) -> str:

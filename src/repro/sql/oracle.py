@@ -98,18 +98,24 @@ def run(plan: LNode, tables: dict[str, Table]) -> Table:
     if isinstance(plan, LAggregate):
         t = run(plan.child, tables)
         n = len(next(iter(t.values()))) if t else 0
+        out: Table = {}
         if plan.group_cols:
-            keys = np.stack([t[c] for c in plan.group_cols], axis=1)
-            uniq, inv = np.unique(keys, axis=0, return_inverse=True)
-            g = uniq.shape[0]
+            # keys of mixed types (numbers, byte strings) group by a
+            # lexicographic sort and its runs
+            keys = [t[c] for c in plan.group_cols]
+            order = np.lexsort(keys[::-1])
+            first = np.zeros(n, bool)
+            first[:1] = True
+            for k in keys:
+                first[1:] |= k[order][1:] != k[order][:-1]
+            inv = np.empty(n, np.int64)
+            inv[order] = np.cumsum(first) - 1
+            g = int(first.sum())
+            for c in plan.group_cols:
+                out[c] = t[c][order][first]
         else:
-            uniq = None
             inv = np.zeros(n, dtype=np.int64)
             g = 1
-        out: Table = {}
-        if uniq is not None:
-            for i, c in enumerate(plan.group_cols):
-                out[c] = uniq[:, i].astype(t[c].dtype)
         for name, fn, arg in plan.aggs:
             if fn == "count":
                 vals = np.ones(n)

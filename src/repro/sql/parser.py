@@ -6,11 +6,12 @@ generated property-test queries):
     SELECT item [, item]*
     FROM table [, table]* [JOIN table ON col = col]*
     [WHERE pred]
-    [GROUP BY expr [, expr]*]
+    [GROUP BY expr [, expr]* [HAVING pred]]
     [ORDER BY expr [ASC|DESC] [, ...]]
     [LIMIT n]
 
-Expressions: + - * /, comparisons, AND/OR/NOT, BETWEEN, IN (...), LIKE,
+Expressions: + - * /, comparisons, AND/OR/NOT, BETWEEN, IN (...),
+IN (SELECT ...), LIKE,
 CASE WHEN .. THEN .. ELSE .. END, DATE 'yyyy-mm-dd', INTERVAL 'n' unit,
 aggregates SUM/AVG/MIN/MAX/COUNT(*).
 """
@@ -33,7 +34,7 @@ KEYWORDS = {
     "select", "from", "where", "group", "by", "order", "limit", "and", "or",
     "not", "between", "in", "like", "case", "when", "then", "else", "end",
     "as", "asc", "desc", "date", "interval", "year", "month", "day", "join",
-    "on", "sum", "avg", "count", "min", "max", "distinct",
+    "on", "sum", "avg", "count", "min", "max", "distinct", "having",
 }
 
 
@@ -99,6 +100,11 @@ class Parser:
 
     # -- statement -----------------------------------------------------------
     def parse(self) -> ast.SelectStmt:
+        stmt = self._select()
+        self.expect("eof")
+        return stmt
+
+    def _select(self) -> ast.SelectStmt:
         self.expect("kw", "select")
         items = [self._select_item()]
         while self.accept("op", ","):
@@ -123,6 +129,7 @@ class Parser:
             group_by.append(self._expr())
             while self.accept("op", ","):
                 group_by.append(self._expr())
+        having = self._expr() if self.accept("kw", "having") else None
         order_by: list[ast.OrderItem] = []
         if self.accept("kw", "order"):
             self.expect("kw", "by")
@@ -132,9 +139,9 @@ class Parser:
         limit = None
         if self.accept("kw", "limit"):
             limit = int(self.expect("num").value)
-        self.expect("eof")
         return ast.SelectStmt(tuple(items), tuple(tables), tuple(joins),
-                              where, tuple(group_by), tuple(order_by), limit)
+                              where, tuple(group_by), tuple(order_by), limit,
+                              having)
 
     def _select_item(self) -> ast.SelectItem:
         e = self._expr()
@@ -190,6 +197,10 @@ class Parser:
         if t.kind == "kw" and t.value == "in":
             self.next()
             self.expect("op", "(")
+            if self.peek().kind == "kw" and self.peek().value == "select":
+                query = self._select()
+                self.expect("op", ")")
+                return ast.InSubquery(left, query)
             vals = [self._additive()]
             while self.accept("op", ","):
                 vals.append(self._additive())

@@ -29,6 +29,7 @@ from typing import Any
 import numpy as np
 
 from repro.data.catalog import Catalog
+from repro.exec.batch import MIN_CAPACITY
 from repro.exec.expr import expr_from_dict, expr_to_dict
 from repro.sql import ast
 from repro.sql.logical import (LAggregate, LFilter, LJoin, LLimit, LNode,
@@ -449,8 +450,14 @@ class PhysicalPlanner:
             sort_keys = list(node.keys)
             node = node.child
         post_project: tuple[tuple[str, ast.Expr], ...] | None = None
-        agg_node = None
-        if isinstance(node, LProject) and isinstance(node.child, LAggregate):
+        agg_node = having = None
+        if isinstance(node, LProject) and isinstance(node.child, LFilter) \
+                and isinstance(node.child.child, LAggregate):
+            post_project = node.exprs
+            agg_node, having = node.child.child, node.child.pred
+            output_names = [n for n, _ in post_project]
+        elif isinstance(node, LProject) and isinstance(node.child,
+                                                       LAggregate):
             post_project = node.exprs
             agg_node = node.child
             output_names = [n for n, _ in post_project]
@@ -460,7 +467,7 @@ class PhysicalPlanner:
             output_names = sorted(_columns_of_logical(node))
 
         if agg_node is not None:
-            root = self._compile_aggregate(agg_node, post_project,
+            root = self._compile_aggregate(agg_node, having, post_project,
                                            sort_keys, limit)
         else:
             root = self._compile_streaming_query(node, sort_keys, limit)
@@ -502,35 +509,68 @@ class PhysicalPlanner:
         return fpid
 
     # -- aggregation queries ----------------------------------------------------
-    def _compile_aggregate(self, agg: LAggregate, post_project,
-                           sort_keys, limit) -> int:
+    def _partial_pipeline(self, agg: LAggregate):
+        """The partial aggregation of ``agg`` as a pipeline of its own,
+        exchanged on the group keys; returns (its pid, the merge's
+        fragments, the merge op reading its exchange, the estimated
+        bytes of the partials).
+
+        Where the input is estimated to fit the smallest block, a sorted
+        partial would fold next to nothing and still sort the whole
+        probe-side capacity, whose compile grows with every key: the
+        pipeline then hands its rows on and the merge aggregates them
+        whole."""
         op, deps, units, in_bytes, sub = self._stream(agg.child)
         strategy, sizes = self._agg_strategy(agg)
         aggs_ser = [[name, fn, expr_to_dict(arg) if arg else None]
                     for name, fn, arg in agg.aggs]
-        partial_op = {"t": "partial_agg", "child": op,
-                      "group_cols": list(agg.group_cols),
-                      "aggs": aggs_ser, "strategy": strategy,
-                      "sizes": sizes}
         tv = self._tables_version(agg)
-        partial_sem = _h(("partial_agg", agg.key(), tv))
         n_frag = min(self._workers_for_bytes(in_bytes),
                      max(len(units), 1)) if units else 1
 
         partial_schema = _agg_schema(agg, self.catalog)
-        if strategy == "direct" or not agg.group_cols:
-            n_dest, merge_frags = 1, 1
+        # float and byte-string keys, which the resident sort kernel's
+        # 32-bit integer key lanes cannot carry (named only where there
+        # are some)
+        wide = [s["name"] for s in partial_schema
+                if s["name"] in agg.group_cols
+                and (s["kind"] == "bytes" or np.dtype(s["dtype"]).kind
+                     == "f")]
+        # rows equal on every key share the hash of any subset of them,
+        # so the exchange hashes the keys that are not byte strings
+        keys = [s["name"] for s in partial_schema
+                if s["name"] in agg.group_cols and s["kind"] != "bytes"]
+        er_child, eb_child = self._est(agg.child)
+        ar, ab = self._est(agg)
+        whole = strategy == "sort" and er_child <= MIN_CAPACITY
+        if strategy == "direct" or not keys or whole:
+            n_dest = 1
         else:
             n_dest = self.config.exchange_partitions or \
                 max(1, min(n_frag, 16))
-            merge_frags = n_dest
-        er_child, eb_child = self._est(agg.child)
-        ar, ab = self._est(agg)
-        partial_rows = min(er_child, ar * n_frag)
-        partial_bytes = min(eb_child, ab * n_frag)
+        if whole:
+            partial_sem = _h(("agg_input", agg.key(), tv))
+            partial_op, partial_schema = op, _output_schema_of(
+                agg.child, self.catalog)
+            partial_rows, partial_bytes = er_child, eb_child
+            merge_aggs = aggs_ser
+        else:
+            partial_sem = _h(("partial_agg", agg.key(), tv))
+            partial_op = {"t": "partial_agg", "child": op,
+                          "group_cols": list(agg.group_cols),
+                          "aggs": aggs_ser, "strategy": strategy,
+                          "sizes": sizes}
+            if wide:
+                partial_op["wide_keys"] = wide
+            partial_rows = min(er_child, ar * n_frag)
+            partial_bytes = min(eb_child, ab * n_frag)
+            merge_aggs = [[name, {"sum": "sum", "count": "sum",
+                                  "min": "min", "max": "max"}[fn],
+                           expr_to_dict(ast.Col(name))]
+                          for name, fn, _ in agg.aggs]
         if n_dest > 1:
-            part, est_xreq = self._pick_exchange(
-                n_frag, agg.group_cols, n_dest, partial_bytes)
+            part, est_xreq = self._pick_exchange(n_frag, keys, n_dest,
+                                                 partial_bytes)
         else:
             part, est_xreq = Partitioning("none"), 0
         ppid = self._new_pid()
@@ -542,16 +582,35 @@ class PhysicalPlanner:
                             est_exchange_requests=est_xreq),
             partial_schema, units)
 
-        merge_aggs = [[name, {"sum": "sum", "count": "sum", "min": "min",
-                              "max": "max"}[fn],
-                       expr_to_dict(ast.Col(name))]
-                      for name, fn, _ in agg.aggs]
         merge_child = {"t": "scan_exchange", "source": partial_sem,
                        "mode": "partition" if n_dest > 1 else "all"}
         merge_op: dict = {"t": "merge_agg", "child": merge_child,
                           "group_cols": list(agg.group_cols),
                           "aggs": merge_aggs, "strategy": strategy,
                           "sizes": sizes}
+        if wide:
+            merge_op["wide_keys"] = wide
+        return ppid, n_dest, merge_op, partial_bytes
+
+    def _stream_aggregate(self, agg: LAggregate):
+        """An aggregation below the root (a derived relation): its
+        partials exchange on the group keys, and the merge is the leaf of
+        the pipeline that streams on from it, one fragment per partition,
+        so a HAVING above it filters in the merge program."""
+        ppid, n_dest, merge_op, partial_bytes = self._partial_pipeline(agg)
+        deps = [ppid] + ([("_n_frag", n_dest)] if n_dest > 1 else [])
+        return merge_op, deps, [], int(partial_bytes), agg
+
+    def _compile_aggregate(self, agg: LAggregate, having, post_project,
+                           sort_keys, limit) -> int:
+        ppid, n_dest, merge_op, partial_bytes = self._partial_pipeline(agg)
+        partial_schema = _agg_schema(agg, self.catalog)
+        tv = self._tables_version(agg)
+        merge_frags = n_dest
+        ar, ab = self._est(agg)
+        if having is not None:
+            merge_op = {"t": "filter", "child": merge_op,
+                        "pred": expr_to_dict(having)}
         if post_project is not None:
             merge_op = {"t": "project", "child": merge_op,
                         "exprs": [[n, expr_to_dict(e)]
@@ -569,11 +628,14 @@ class PhysicalPlanner:
         else:
             out_schema = partial_schema
 
+        # the HAVING joins the hashes only where there is one, so the
+        # pipelines of every other aggregation keep their identity
+        hv = () if having is None else (having.key(),)
         fold_final = merge_frags == 1
         merge_sem = _h(("merge_agg", agg.key(),
                         tuple((n, e.key()) for n, e in (post_project or ())),
                         tuple(sort_keys) if fold_final else (),
-                        limit if fold_final else None, tv))
+                        limit if fold_final else None, tv) + hv)
         if fold_final and (sort_keys or limit is not None):
             merge_op = {"t": "final", "child": merge_op, "project": None,
                         "sort_keys": [[k, d] for k, d in sort_keys],
@@ -591,7 +653,7 @@ class PhysicalPlanner:
 
         fsem = _h(("final", agg.key(),
                    tuple((n, e.key()) for n, e in (post_project or ())),
-                   tuple(sort_keys), limit, tv))
+                   tuple(sort_keys), limit, tv) + hv)
         fop = {"t": "final",
                "child": {"t": "scan_exchange", "source": merge_sem,
                          "mode": "all"},
@@ -647,6 +709,8 @@ class PhysicalPlanner:
                     deps, units, nbytes, node)
         if isinstance(node, LJoin):
             return self._stream_join(node)
+        if isinstance(node, LAggregate):
+            return self._stream_aggregate(node)
         raise TypeError(f"not streamable: {node}")
 
     def _stream_join(self, node: LJoin):
@@ -845,8 +909,6 @@ def _output_schema_of(node: LNode, catalog: Catalog) -> list[dict]:
     for c in sorted(_columns_of_logical(node)):
         ct = _column_type(node, c, catalog)
         kind, dtype, _ = ct if ct else ("num", "<f8", None)
-        if kind == "bytes":
-            continue  # opaque strings are pruned before execution
         out.append({"name": c, "kind": kind, "dtype": dtype})
     return out
 
@@ -856,7 +918,11 @@ def _agg_schema(agg: LAggregate, catalog: Catalog) -> list[dict]:
     for c in agg.group_cols:
         ct = _column_type(agg.child, c, catalog)
         kind, dtype, _ = ct if ct else ("num", "<i8", None)
-        out.append({"name": c, "kind": kind, "dtype": "<i8"})
+        # keys leave the program as int64, byte strings and floats as
+        # they came
+        if kind != "bytes" and np.dtype(dtype).kind != "f":
+            dtype = "<i8"
+        out.append({"name": c, "kind": kind, "dtype": dtype})
     for name, fn, _ in agg.aggs:
         out.append({"name": name, "kind": "num", "dtype": "<f8"})
     return out

@@ -97,5 +97,23 @@ where p_partkey = l_partkey
     and l_shipinstruct = 'DELIVER IN PERSON'
 """
 
+# Large Volume Customer, clause 2.4.18, with its validation QUANTITY 300
+TPCH_Q18 = """
+select
+    c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice,
+    sum(l_quantity)
+from customer, orders, lineitem
+where o_orderkey in (
+        select l_orderkey
+        from lineitem
+        group by l_orderkey
+        having sum(l_quantity) > 300)
+    and c_custkey = o_custkey
+    and o_orderkey = l_orderkey
+group by c_name, c_custkey, o_orderkey, o_orderdate, o_totalprice
+order by o_totalprice desc, o_orderdate
+limit 100
+"""
+
 QUERIES = {"q1": TPCH_Q1, "q3": TPCH_Q3, "q6": TPCH_Q6, "q12": TPCH_Q12,
            "q14": TPCH_Q14, "q19": TPCH_Q19}
