@@ -155,8 +155,9 @@ class PipelineReport:
     # pipeline's wall time. ``fragment.*`` spans come from the workers,
     # ``coord.*`` from the coordinator waits this pipeline blocked on.
     # ``counters`` sums the workers' ``decode_ns``, ``h2d_bytes``,
-    # ``d2h_bytes``, and ``agg_rows_in`` and ``agg_groups_out`` where
-    # the program aggregates; ``ops`` names the pipeline's operators,
+    # ``d2h_bytes``, ``agg_rows_in`` and ``agg_groups_out`` where the
+    # program aggregates, and ``join_merge_rows`` and ``join_search_rows``
+    # where it runs a generic join; ``ops`` names the pipeline's operators,
     # root first, so a reader can tell partial from merge aggregation.
     span_ms: dict = dataclasses.field(default_factory=dict)
     counters: dict = dataclasses.field(default_factory=dict)
@@ -1452,7 +1453,8 @@ class QueryEngine:
                     report.span_ms[name] = report.span_ms.get(name, 0.0) \
                         + (t1 - t0) / 1e6
                 for k in ("decode_ns", "h2d_bytes", "d2h_bytes",
-                          "agg_rows_in", "agg_groups_out"):
+                          "agg_rows_in", "agg_groups_out",
+                          "join_merge_rows", "join_search_rows"):
                     report.counters[k] = report.counters.get(k, 0) \
                         + s.get(k, 0)
                 if count_payload:           # …but a duplicate's output

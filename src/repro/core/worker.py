@@ -10,8 +10,9 @@ partitions parse each footer once per object version.
 
 The response's ``stats`` carry the fragment's host spans as ``(name,
 start_ns, end_ns)`` on the worker's ``time.perf_counter_ns``, with the
-``decode_ns``, ``h2d_bytes``, ``d2h_bytes``, ``agg_rows_in`` and
-``agg_groups_out`` counters. In a real
+``decode_ns``, ``h2d_bytes``, ``d2h_bytes``, ``agg_rows_in``,
+``agg_groups_out``, ``join_merge_rows`` and ``join_search_rows``
+counters. In a real
 deployment each worker's clock is its own, so only durations compare
 across workers; in this single-process simulation every worker runs on
 the coordinator's host, and the spans share its clock (and a profiler
@@ -84,6 +85,8 @@ def make_worker_handler(store: ObjectStore,
                 "d2h_bytes": stats.d2h_bytes,
                 "agg_rows_in": stats.agg_rows_in,
                 "agg_groups_out": stats.agg_groups_out,
+                "join_merge_rows": stats.join_merge_rows,
+                "join_search_rows": stats.join_search_rows,
             },
         }
         return response, sim_runtime

@@ -129,13 +129,40 @@ def from_numpy(columns: dict[str, np.ndarray],
     return Block(cols, jnp.asarray(mask))
 
 
-def _ahead(x, s: int):
+def shift_ahead(x, s: int):
     """``x`` moved ``s`` rows towards the front, zeros behind."""
     return jnp.concatenate([x[s:], jnp.zeros((s,), x.dtype)])
 
 
-def _behind(x, s: int):
+def shift_behind(x, s: int):
     return jnp.concatenate([jnp.zeros((s,), x.dtype), x[:-s]])
+
+
+def _gaps(mask: jnp.ndarray) -> jnp.ndarray:
+    """int32 per valid row: the invalid rows before it (0 on the others).
+    The prefix count is a doubling scan of slices and selects."""
+    cap = mask.shape[0]
+    upto = mask.astype(jnp.int32)
+    s = 1
+    while s < cap:
+        upto = upto + shift_behind(upto, s)
+        s *= 2
+    return jnp.where(mask, lax.iota(jnp.int32, cap) + 1 - upto, 0)
+
+
+def _to_front(cols: dict, live: jnp.ndarray, gap: jnp.ndarray):
+    """``cols`` with each ``live`` row moved ``gap`` rows towards the
+    front, lowest binary digit first; (cols, gap) as they end."""
+    s = 1
+    while s < live.shape[0]:
+        move = live & ((gap & s) != 0)
+        arrive = shift_ahead(move, s)
+        cols = {n: jnp.where(arrive, shift_ahead(c, s), c)
+                for n, c in cols.items()}
+        gap = jnp.where(arrive, shift_ahead(gap, s), gap)
+        live = arrive | (live & ~move)
+        s *= 2
+    return cols, gap
 
 
 def compact(columns: dict[str, jnp.ndarray], mask: jnp.ndarray):
@@ -151,24 +178,29 @@ def compact(columns: dict[str, jnp.ndarray], mask: jnp.ndarray):
     a sort or ``cumsum`` over it takes seconds to compile there; a select
     moves values with their bits.
     """
-    cap = mask.shape[0]
-    upto = mask.astype(jnp.int32)
-    s = 1
-    while s < cap:
-        upto = upto + _behind(upto, s)
-        s *= 2
-    gap = jnp.where(mask, lax.iota(jnp.int32, cap) + 1 - upto, 0)
-    live, cols = mask, dict(columns)
-    s = 1
-    while s < cap:
-        move = live & ((gap & s) != 0)
-        arrive = _ahead(move, s)
-        cols = {n: jnp.where(arrive, _ahead(c, s), c)
-                for n, c in cols.items()}
-        gap = jnp.where(arrive, _ahead(gap, s), gap)
-        live = arrive | (live & ~move)
-        s *= 2
+    cols, _ = _to_front(dict(columns), mask, _gaps(mask))
     return cols, jnp.sum(mask, dtype=jnp.int32)
+
+
+def expand(columns: dict[str, jnp.ndarray], mask: jnp.ndarray):
+    """The inverse of ``compact``: the first ``sum(mask)`` rows of each
+    column, in their order, moved to the rows where ``mask`` is set.
+    ``compact``'s steps run backwards, highest binary digit first, on
+    the distances its valid rows travel. Traceable."""
+    cap = mask.shape[0]
+    _, gap = _to_front({}, mask, _gaps(mask))
+    live = lax.iota(jnp.int32, cap) < jnp.sum(mask, dtype=jnp.int32)
+    cols = dict(columns)
+    s = (1 << (cap - 1).bit_length()) >> 1
+    while s:
+        move = live & ((gap & s) != 0)
+        arrive = shift_behind(move, s)
+        cols = {n: jnp.where(arrive, shift_behind(c, s), c)
+                for n, c in cols.items()}
+        gap = jnp.where(arrive, shift_behind(gap, s), gap)
+        live = arrive | (live & ~move)
+        s >>= 1
+    return cols
 
 
 # one program per (columns' shapes, prefix bucket): at most one per power

@@ -78,6 +78,11 @@ class FragmentStats:
     # output's valid-row count, past any HAVING)
     agg_rows_in: int = 0
     agg_groups_out: int = 0
+    # a fragment whose program runs a generic join: for each such join,
+    # its probe leaf's valid rows, under the plan the join's capacities
+    # chose (``operators.join_merges``)
+    join_merge_rows: int = 0
+    join_search_rows: int = 0
 
     @contextlib.contextmanager
     def span(self, name: str):
@@ -287,6 +292,35 @@ def _warm(fn, key, blocks) -> None:
         _WARM_SHAPES.add(sig)
 
 
+def _generic_joins(op: dict, caps: dict) -> list[tuple[str, bool]]:
+    """(probe leaf id, whether it merges) of every generic join in an op
+    tree at leaf capacities ``caps``, the leaves numbered as ``_build``
+    numbers them, each join's capacities as its operands have them."""
+    joins: list[tuple[str, bool]] = []
+    n_leaves = [0]
+
+    def rows(o: dict) -> tuple[int, str]:
+        t = o["t"]
+        if t in ("scan_table", "scan_exchange"):
+            leaf_id = f"in{n_leaves[0]}"
+            n_leaves[0] += 1
+            return caps[leaf_id], leaf_id
+        if t == "join":
+            p, leaf_id = rows(o["probe"])
+            b, _ = rows(o["build"])
+            joins.append((leaf_id, ops.join_merges(p, b)))
+            return p, leaf_id
+        n, leaf_id = rows(o["child"])
+        if t == "semijoin_probe":
+            n_leaves[0] += 1        # the filter's words
+        if t in ("partial_agg", "merge_agg") and o["strategy"] == "direct":
+            n = int(np.prod(o["sizes"])) if o["group_cols"] else 1
+        return n, leaf_id
+
+    rows(op)
+    return joins
+
+
 def _aggregates(op: dict) -> bool:
     return op["t"] in ("partial_agg", "merge_agg") or any(
         _aggregates(op[k]) for k in ("child", "probe", "build") if k in op)
@@ -493,6 +527,7 @@ def execute_fragment(store: ObjectStore, spec: dict,
 
     # 1. Load leaf inputs (host side, ranged + pruned + re-triggered reads).
     blocks = {}
+    leaf_rows = {}
     for leaf_id, leaf_op in leaves:
         if leaf_op["t"] == "bloom_words":
             words = np.frombuffer(sj["words"], dtype=np.uint32)
@@ -507,6 +542,7 @@ def execute_fragment(store: ObjectStore, spec: dict,
                                            leaf_op, stats)
         n = len(next(iter(cols.values()))) if cols else 0
         stats.rows_in += n
+        leaf_rows[leaf_id] = n
         with stats.span("fragment.h2d"):
             blk = from_numpy(cols, bucket_capacity(n))
         stats.h2d_bytes += blk.mask.nbytes + sum(
@@ -515,10 +551,17 @@ def execute_fragment(store: ObjectStore, spec: dict,
 
     # A matched kernel may still take its XLA fallback at these capacities
     # (past a resident cap): only a kernel that runs is reported.
-    if kernel and runs_kernel(lower.leaf_capacities(blocks)):
+    caps = lower.leaf_capacities(blocks)
+    if kernel and runs_kernel(caps):
         stats.kernel = kernel
         stats.kernel_mode = ("interpreted" if kcommon.interpret_mode()
                              else "compiled")
+    else:
+        for leaf_id, merges in _generic_joins(op, caps):
+            if merges:
+                stats.join_merge_rows += leaf_rows[leaf_id]
+            else:
+                stats.join_search_rows += leaf_rows[leaf_id]
 
     # 2. Execute the fused XLA program (trace/compile paid untimed, once
     # per op×capacity bucket — simulated runtime is steady-state compute).

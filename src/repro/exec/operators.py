@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.exec.batch import lanes_of, renamed_lanes
+from repro.exec.batch import (compact, expand, lanes_of, renamed_lanes,
+                              shift_ahead, shift_behind)
 from repro.exec.expr import compile_expr
 from repro.kernels.common import NEUTRAL
 from repro.sql import ast
@@ -239,31 +240,106 @@ def merge_aggs_spec(aggs: list[tuple[str, str, ast.Expr | None]]):
 
 # -- join -----------------------------------------------------------------------
 
+# A probe row's binary search costs one dependent gather of the sorted build
+# keys per halving, ceil(log2 B) in all, and one gather per payload lane; the
+# sort-merge moves every row of both sides through two sorts and two doubling
+# scans. On the TPU a gathered element costs about as much as this many rows
+# of the merge (PERF.md, the join threshold measurement).
+MERGE_ROW_COST = 4
+
+
+def join_merges(probe_rows: int, build_rows: int) -> bool:
+    """Whether a join of these static capacities takes the sort-merge
+    (else the binary search): a large probe side merges, a small probe
+    side against a large build side searches."""
+    steps = max(1, (build_rows - 1).bit_length())
+    return probe_rows * steps > MERGE_ROW_COST * (probe_rows + build_rows)
+
+
 def make_pk_join_probe(probe_key: str, build_key: str,
                        payload_cols: list[str]):
-    """FK→PK equi-join: binary-search probe against the sorted build side.
+    """FK→PK equi-join: each probe row meets the build row of its key.
 
     Build keys are unique (PK) so each probe row matches ≤ 1 build row.
     Output occupies the probe block; misses/invalid rows are masked out.
+    The plan follows the static capacities (``join_merges``): a sort-merge
+    of both sides, or a binary search of the sorted build side. A payload
+    lane that is the build key itself is the probe key on every hit.
     """
 
     def op(probe_cols: Cols, probe_mask, build_cols: Cols, build_mask):
         bk = jnp.where(build_mask, build_cols[build_key].astype(jnp.int64),
                        INT64_SENTINEL)
-        order = jnp.argsort(bk)
-        sk = bk[order]
         pk = probe_cols[probe_key].astype(jnp.int64)
-        pos = jnp.searchsorted(sk, pk)
-        pos_c = jnp.clip(pos, 0, sk.shape[0] - 1)
-        hit = (sk[pos_c] == pk) & probe_mask & (pk != INT64_SENTINEL)
-        sel = order[pos_c]
+        lanes = [lane for c in payload_cols for lane in lanes_of(build_cols, c)
+                 if lane not in probe_cols and lane != build_key]
+        probe = _merge_probe if join_merges(pk.shape[0], bk.shape[0]) \
+            else _search_probe
+        hit, got = probe(pk, bk, [build_cols[lane] for lane in lanes])
         out = dict(probe_cols)
-        for c in payload_cols:
-            for lane in lanes_of(build_cols, c):
-                if lane not in out:
-                    out[lane] = build_cols[lane][sel]
-        return out, hit
+        out.update(zip(lanes, got))
+        if build_key in payload_cols and build_key not in probe_cols:
+            out[build_key] = pk.astype(build_cols[build_key].dtype)
+        return out, hit & probe_mask & (pk != INT64_SENTINEL)
     return op
+
+
+def _search_probe(pk, bk, payload):
+    """(hit, payload lanes at the probe rows): a binary search of the
+    sorted build keys per probe row, then a gather per lane."""
+    order = jnp.argsort(bk)
+    sk = bk[order]
+    pos = jnp.clip(jnp.searchsorted(sk, pk), 0, sk.shape[0] - 1)
+    sel = order[pos]
+    return sk[pos] == pk, [v[sel] for v in payload]
+
+
+def _merge_probe(pk, bk, payload):
+    """(hit, payload lanes at the probe rows) with no gather or scatter
+    over the probe side, in two one-key sorts. The first sorts both
+    sides' keys; the build rows' payload, gathered over the build side in
+    key order, is spread out to them (``expand``); each probe row takes
+    the key and payload of the build row in its key's run; the second
+    sort brings the probe rows back to their order. An unstable sort
+    leaves the build row anywhere in its run, so the run's probe rows
+    look for it both behind and ahead of them. Only 32-bit sort keys
+    carry payload: a 64-bit key with payload compiles for minutes."""
+    P, B = pk.shape[0], bk.shape[0]
+    key = jnp.concatenate([bk, pk])
+    row = jnp.concatenate([-1 - jax.lax.iota(jnp.int32, B),
+                           jax.lax.iota(jnp.int32, P)])
+    key, row = jax.lax.sort((key, row), num_keys=1, is_stable=False)
+    build = row < 0
+    lanes = []
+    if payload:
+        order = compact({"b": -1 - row}, build)[0]["b"][:B]
+        spread = expand({i: jnp.concatenate([v[order],
+                                             jnp.zeros((P,), v.dtype)])
+                         for i, v in enumerate(payload)}, build)
+        lanes = [spread[i] for i in range(len(payload))]
+    behind = _carry(build, [key, *lanes], shift_behind)
+    ahead = _carry(build, [key, *lanes], shift_ahead)
+    from_behind = behind[0] & (behind[1] == key)
+    hit = from_behind | (ahead[0] & (ahead[1] == key))
+    got = [jnp.where(from_behind, b, a)
+           for b, a in zip(behind[2:], ahead[2:])]
+    # probe order: the probe row is the key, its hit the lowest bit; the
+    # build rows sort last and are cut off
+    dest = jnp.where(build, 2 * P, 2 * row + hit.astype(jnp.int32))
+    dest, *got = jax.lax.sort((dest, *got), num_keys=1, is_stable=False)
+    return (dest[:P] & 1) == 1, [g[:P] for g in got]
+
+
+def _carry(src, values, shift):
+    """(whether a ``src`` row was found, *values at it): for every row, the
+    nearest ``src`` row at or behind it (``shift`` = ``shift_behind``) or at
+    or ahead of it (``shift_ahead``). A doubling scan of slices and selects."""
+    found, n, s = src, src.shape[0], 1
+    while s < n:
+        values = [jnp.where(found, v, shift(v, s)) for v in values]
+        found = found | shift(found, s)
+        s *= 2
+    return [found, *values]
 
 
 # -- exchange partitioning -------------------------------------------------------
