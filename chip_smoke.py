@@ -50,7 +50,7 @@ SORT_AGG_SQL = ("select s_nationkey, sum(s_acctbal) as balance, "
 MINMAX_SQL = ("select l_returnflag, min(l_quantity) as min_qty, "
               "max(l_extendedprice) as max_price from lineitem "
               "group by l_returnflag")
-# the selective join of benchmarks/suites.py:bench_semijoin
+# the selective join of tests/test_semijoin.py (SELECTIVE_JOIN)
 SEMIJOIN_SQL = ("select l_orderkey, sum(l_extendedprice) as rev "
                 "from lineitem, orders "
                 "where l_orderkey = o_orderkey and o_totalprice > 500000 "

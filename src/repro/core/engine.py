@@ -66,6 +66,22 @@ from repro.sql.rules import optimize
 from repro.storage.io_handlers import InputHandler
 from repro.storage.object_store import ObjectStore
 
+# A fragment whose runtime exceeds this multiple of its fleet's fast
+# quartile (floored at ``CoordinatorConfig.straggler_min_timeout_s``) is
+# re-triggered.
+STRAGGLER_DETECT_FACTOR = 3.0
+# Fleets at least this wide are dispatched two-level (√W invokers).
+TWO_LEVEL_THRESHOLD = 16
+# Simulated time the coordinator spends polling a fleet's responses.
+RESPONSE_POLL_OVERHEAD_S = 0.01
+# The latency budget adaptive re-optimization sizes fleets under.
+ADAPTIVE_LATENCY_BUDGET_S = 2.0
+# Pilot-K: a pipelined consumer re-optimizes on the first K landed
+# producers' stats, extrapolated to the fleet.
+PILOT_K = 2
+# Longest a pipelined consumer or merge wave waits on an upstream stream.
+PIPELINED_WAIT_TIMEOUT_S = 600.0
+
 
 class QueryAborted(QueryFailedError):
     """Permanent query failure with a post-mortem (bad plan, repeated
@@ -201,11 +217,8 @@ class QueryResult:
 @dataclasses.dataclass
 class CoordinatorConfig:
     planner: PlannerConfig = dataclasses.field(default_factory=PlannerConfig)
-    straggler_detect_factor: float = 3.0
     straggler_min_timeout_s: float = 0.5
     max_attempts: int = 3
-    two_level_threshold: int = 16
-    response_poll_overhead_s: float = 0.01
     use_result_cache: bool = True
     # Adaptive re-optimization at stage barriers (core.adaptive): re-size
     # downstream fleets cost-optimally under the latency budget, prune
@@ -213,7 +226,6 @@ class CoordinatorConfig:
     # when the observed build side fits the memory budget (None → the
     # planner's broadcast threshold), and re-pick exchange tiers.
     adaptive: bool = True
-    adaptive_latency_budget_s: float = 2.0
     broadcast_downgrade_bytes: int | None = None
     # Persist observed per-(table, predicate) selectivities in the KV
     # tier and seed the planner's estimates with them (downward-only),
@@ -224,17 +236,12 @@ class CoordinatorConfig:
     # launches once the admission fraction of each upstream fleet's
     # partitions has landed *and* that fleet is fully submitted (the
     # deadlock-freedom gate), tops up as later manifests arrive, and
-    # re-optimizes on the first `pilot_k` producers' observed stats
-    # extrapolated to the fleet. `pipelined=False` restores the
-    # bit-compatible all-or-nothing stage-barrier schedule.
-    # `pipeline_start_fraction=None` (the default) lets the cost model
-    # choose the fraction per upstream fleet from its observed runtime
-    # skew (CostModel.pipeline_admission_fraction); a float forces that
-    # constant fraction everywhere, e.g. the seed behavior's 0.5.
+    # re-optimizes on the first PILOT_K producers' observed stats
+    # extrapolated to the fleet. The cost model chooses the fraction per
+    # upstream fleet from its observed runtime skew
+    # (CostModel.pipeline_admission_fraction). `pipelined=False` restores
+    # the bit-compatible all-or-nothing stage-barrier schedule.
     pipelined: bool = True
-    pipeline_start_fraction: float | None = None
-    pilot_k: int = 2
-    pipelined_wait_timeout_s: float = 600.0
     # Scan-selectivity pilot: an uncalibrated scan→filter pipeline with
     # at least this many scan units probes one unit first and records
     # the observed selectivity before the fleet launches.
@@ -315,7 +322,7 @@ class QueryEngine:
             if cfg.calibrate_selectivity else None
         self.reoptimizer = Reoptimizer(
             self.cost_model,
-            latency_budget_s=cfg.adaptive_latency_budget_s,
+            latency_budget_s=ADAPTIVE_LATENCY_BUDGET_S,
             broadcast_bytes=(cfg.broadcast_downgrade_bytes
                              if cfg.broadcast_downgrade_bytes is not None
                              else cfg.planner.broadcast_threshold_bytes),
@@ -460,15 +467,6 @@ class QueryEngine:
         return QueryResult(self._result_locations(root),
                            plan.output_names, stats)
 
-    def _admission_fraction(self, completions_s: list[float]) -> float:
-        """The consumer-admission fraction for one upstream fleet: the
-        config's forced constant when set, else the cost model's pick
-        from the fleet's observed completion skew."""
-        f = self.config.pipeline_start_fraction
-        if f is not None:
-            return f
-        return self.cost_model.pipeline_admission_fraction(completions_s)
-
     def _sim_timeline(self, plan: PhysicalPlan, stages: list[list[int]],
                       reports: dict[int, PipelineReport],
                       stats: QueryStats) -> None:
@@ -489,7 +487,7 @@ class QueryEngine:
                     if rr.cache_hit:
                         continue
                     if r.pipelined:
-                        frac = self._admission_fraction(
+                        frac = self.cost_model.pipeline_admission_fraction(
                             rr.producer_completions)
                         avail = (rr.sim_start_s + rr.dispatch_s
                                  + CostModel.pipeline_start_offset_s(
@@ -662,7 +660,7 @@ class QueryEngine:
             for f in range(p.n_fragments)
         }
 
-        two_level = p.n_fragments >= cfg.two_level_threshold
+        two_level = p.n_fragments >= TWO_LEVEL_THRESHOLD
         dispatch = self.platform.dispatch_time_s(p.n_fragments,
                                                  two_level=two_level)
         report.dispatch_s = dispatch
@@ -754,7 +752,7 @@ class QueryEngine:
             if len(completions) >= 2:
                 runtimes = np.array(list(completions.values()))
                 fast = float(np.percentile(runtimes, 25, method="lower"))
-                threshold = max(cfg.straggler_detect_factor * fast,
+                threshold = max(STRAGGLER_DETECT_FACTOR * fast,
                                 cfg.straggler_min_timeout_s)
                 for f, t in list(completions.items()):
                     if t > threshold:
@@ -795,7 +793,7 @@ class QueryEngine:
 
         report.sim_s += (dispatch
                          + self._sim_makespan(list(completions.values()))
-                         + cfg.response_poll_overhead_s)
+                         + RESPONSE_POLL_OVERHEAD_S)
         report.producer_completions = self._sim_schedule(
             list(completions.values()))
 
@@ -846,10 +844,9 @@ class QueryEngine:
         try:
             gate = self._source_gates.get(p.sem_hash) or {}
             self.registry.await_source_ready(
-                p.sem_hash, fraction=self.config.pipeline_start_fraction,
-                cost_model=self.cost_model,
+                p.sem_hash, cost_model=self.cost_model,
                 stream="l0", cancel_check=self._check_cancel,
-                timeout_s=self.config.pipelined_wait_timeout_s,
+                timeout_s=PIPELINED_WAIT_TIMEOUT_S,
                 min_published_at=gate.get("floor"))
             box["publish_n"] = self._run_merge_wave(
                 p, p.n_fragments, prefix, report, stats, pipelined=True)
@@ -919,7 +916,6 @@ class QueryEngine:
         with the producer tail: wave specs carry the l0 manifest key, so
         each merge worker starts on its group's available l0 objects and
         tops up until the stream seals."""
-        cfg = self.config
         G = exchange.merge_group_count(producers)
         op = p.op["child"] if p.op.get("t") == "final" else p.op
         combine = exchange.combine_spec(op)
@@ -949,7 +945,7 @@ class QueryEngine:
             mop_extra = {
                 "manifest_key": self.registry.partial_key(p.sem_hash,
                                                           "l0"),
-                "wait_timeout_s": cfg.pipelined_wait_timeout_s}
+                "wait_timeout_s": PIPELINED_WAIT_TIMEOUT_S}
 
             def on_all() -> None:
                 self.registry.mark_all_submitted(p.sem_hash, G)
@@ -969,7 +965,7 @@ class QueryEngine:
         } for j in range(G)]
         mreport = PipelineReport(p.pid, p.sem_hash, G)
         dispatch = self.platform.dispatch_time_s(
-            G, two_level=G >= cfg.two_level_threshold)
+            G, two_level=G >= TWO_LEVEL_THRESHOLD)
         extra: list[dict] = []
         results = self.platform.invoke_many(
             self.handler, specs, pipeline=p.pid,
@@ -987,14 +983,15 @@ class QueryEngine:
             # the l0 seal, which follows the producer accounting.
             start = CostModel.pipeline_start_offset_s(
                 report.producer_completions,
-                self._admission_fraction(report.producer_completions))
+                self.cost_model.pipeline_admission_fraction(
+                    report.producer_completions))
             sched = self._sim_schedule([r.sim_runtime_s
                                         for r in results])
             with self._metrics_lock:
                 report.sim_s = max(
                     report.sim_s,
                     report.dispatch_s + start + dispatch + max(sched)
-                    + cfg.response_poll_overhead_s)
+                    + RESPONSE_POLL_OVERHEAD_S)
                 # downstream admission keys on the wave's completions
                 report.producer_completions = [start + dispatch + t
                                                for t in sched]
@@ -1002,7 +999,7 @@ class QueryEngine:
             report.sim_s += (dispatch
                              + self._sim_makespan([r.sim_runtime_s
                                                    for r in results])
-                             + cfg.response_poll_overhead_s)
+                             + RESPONSE_POLL_OVERHEAD_S)
         report.merge_fragments = G
         report.attempts += mreport.attempts
         report.transient_failures += mreport.transient_failures
@@ -1581,14 +1578,12 @@ class QueryEngine:
         pseudo-entry). An aborted upstream stream is waited out — a
         peer that re-claims the failed execution resets it — until our
         own cancel check (sibling abort) or the wait deadline fires."""
-        cfg = self.config
-        deadline = time.time() + cfg.pipelined_wait_timeout_s
+        deadline = time.time() + PIPELINED_WAIT_TIMEOUT_S
         floor = self._await_source_gate(sem, deadline)
         while True:
             try:
                 entry = self.registry.await_source_ready(
-                    sem, fraction=cfg.pipeline_start_fraction,
-                    cost_model=self.cost_model,
+                    sem, cost_model=self.cost_model,
                     cancel_check=self._check_cancel,
                     timeout_s=max(deadline - time.time(), 0.01),
                     min_published_at=floor)
@@ -1659,10 +1654,9 @@ class QueryEngine:
         skips decisions that need the full fleet's observations (e.g.
         empty-partition pruning: a partition empty in the pilot subset
         may still receive rows from later producers)."""
-        cfg = self.config
         done = sorted(int(f) for f in (man.get("done") or {}))
         n = max(int(man.get("n_producers") or 0), len(done), 1)
-        k = min(len(done), max(cfg.pilot_k, 1))
+        k = min(len(done), PILOT_K)
         est: dict = {}
         if k > 0:
             infos = [man["done"][str(f)] for f in done[:k]]
@@ -1699,7 +1693,7 @@ class QueryEngine:
             "schema": man.get("schema"),
             "stats": est,
             "manifest_key": self.registry.partial_key(sem),
-            "wait_timeout_s": cfg.pipelined_wait_timeout_s,
+            "wait_timeout_s": PIPELINED_WAIT_TIMEOUT_S,
         }
 
     def _fragment_spec(self, p: Pipeline, f: int, n: int, prefix: str,

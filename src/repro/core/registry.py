@@ -390,7 +390,7 @@ class ResultRegistry:
                              self.partial_key(sem_hash, stream))
 
     def await_source_ready(self, sem_hash: str, *,
-                           fraction: float | None,
+                           fraction: float | None = None,
                            cost_model=None,
                            stream: str = "partial", cancel_check=None,
                            timeout_s: float | None = None,

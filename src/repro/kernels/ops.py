@@ -1,4 +1,4 @@
-"""Jitted public wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels the engine dispatches to.
 
 On a TPU the kernels compile to Mosaic; on any other backend they run in
 Pallas interpret mode — the kernel body runs as traced jnp on the host,
@@ -8,54 +8,13 @@ so call sites never change.
 
 from __future__ import annotations
 
-from functools import partial
-
-import jax
-
 from repro.kernels import bloom as _bloom
 from repro.kernels import filter_agg as _fa
-from repro.kernels import flash_attention as _flash
 from repro.kernels import groupby_onehot as _go
 from repro.kernels import join_probe as _jp
 from repro.kernels import sort_agg as _sa
-from repro.kernels import ssd_scan as _ssd
 from repro.kernels import topk as _tk
 from repro.kernels import common
-
-
-@partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                   "block_k"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int = _flash.DEFAULT_BLOCK_Q,
-                    block_k: int = _flash.DEFAULT_BLOCK_K):
-    return _flash.flash_attention(
-        q, k, v, causal=causal, window=window, block_q=block_q,
-        block_k=block_k, interpret=common.interpret_mode())
-
-
-@partial(jax.jit, static_argnames=("chunk",))
-def ssd_scan(x, dt, A_log, B, C, *, chunk: int = 128):
-    return _ssd.ssd_scan(x, dt, A_log, B, C, chunk=chunk,
-                         interpret=common.interpret_mode())
-
-
-@partial(jax.jit, static_argnames=("date_lo", "date_hi", "disc_lo",
-                                   "disc_hi", "qty_hi", "block"))
-def filter_agg(shipdate, discount, quantity, extendedprice, *,
-               date_lo: int, date_hi: int, disc_lo: float,
-               disc_hi: float, qty_hi: float,
-               block: int = _fa.BLOCK_ROWS):
-    return _fa.filter_agg(
-        shipdate, discount, quantity, extendedprice, date_lo=date_lo,
-        date_hi=date_hi, disc_lo=disc_lo, disc_hi=disc_hi, qty_hi=qty_hi,
-        block=block, interpret=common.interpret_mode())
-
-
-@partial(jax.jit, static_argnames=("n_groups", "block"))
-def groupby_onehot(group_ids, values, *, n_groups: int,
-                   block: int = _go.BLOCK_ROWS):
-    return _go.groupby_onehot(group_ids, values, n_groups=n_groups,
-                              block=block, interpret=common.interpret_mode())
 
 
 # -- generic fused kernels (engine dispatch targets) --------------------------

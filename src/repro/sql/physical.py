@@ -36,6 +36,8 @@ from repro.sql.logical import (LAggregate, LFilter, LJoin, LLimit, LNode,
                                LProject, LScan, LSort)
 
 DIRECT_AGG_MAX_GROUPS = 4096
+# Selectivity of a filter conjunct no zone map or dictionary estimates.
+FILTER_SELECTIVITY_GUESS = 0.3
 
 
 @dataclasses.dataclass
@@ -50,7 +52,6 @@ class PlannerConfig:
     exchange_partitions: int | None = None
     # Above this many shuffle objects, tier the exchange to hot storage.
     hot_shuffle_object_threshold: int = 64
-    filter_selectivity_guess: float = 0.3
     # Force one shuffle strategy for every hash exchange
     # ("direct" | "combining" | "multilevel"); None → the cost model
     # picks per exchange via ``CostModel.exchange_cost``.
@@ -240,7 +241,7 @@ class PhysicalPlanner:
         fraction from the build side's own selectivity), and filter
         selectivity is estimated per conjunct from catalog zone-map
         hints (numeric min/max ranges, dictionary cardinalities) when
-        available, falling back to ``filter_selectivity_guess``.
+        available, falling back to ``FILTER_SELECTIVITY_GUESS``.
         """
         if isinstance(node, LScan):
             meta = self.catalog.table(node.table)
@@ -308,7 +309,7 @@ class PhysicalPlanner:
         return min(1.0, max(sel, 1e-4))
 
     def _conjunct_selectivity(self, c: ast.Expr, child: LNode) -> float:
-        guess = self.config.filter_selectivity_guess
+        guess = FILTER_SELECTIVITY_GUESS
         if isinstance(c, ast.InList) and isinstance(c.term, ast.Col):
             ct = _column_type(child, c.term.name, self.catalog)
             if ct is not None and ct[0] == "dict" and ct[2]:

@@ -352,6 +352,17 @@ def test_kill_point_sweep_recovers_to_parity(site, seed):
     _assert_same_rows(ref_cols, cols, f"site={site} seed={seed}")
 
 
+def test_zero_probability_engine_injects_nothing():
+    """The off path: an engine attached with every probability zero and
+    no kill point injects nothing, and the rows are the fault-free
+    run's."""
+    ref_cols, _ = _reference("q6")
+    idle = ChaosEngine(ChaosConfig(seed=0))
+    cols, _ = _run("q6", idle)
+    assert idle.injected == {}
+    _assert_same_rows(ref_cols, cols, "zero-probability engine")
+
+
 def test_claim_owner_death_runs_fleet_exactly_once():
     """An owner killed right after writing its claim (before invoking
     anything) leaves an orphan. The re-drive TTL-steals it and runs the

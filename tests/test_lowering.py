@@ -402,6 +402,45 @@ def test_join_queries_run_on_fused_kernels(qname, tpch_store, tpch_tables):
             err_msg=f"{qname}.{k} (fused vs jnp)")
 
 
+_KERNEL_SQLS = {
+    "filter_agg": QUERIES["q6"],
+    "groupby_onehot": QUERIES["q1"],
+    "join_probe_agg": QUERIES["q12"],
+    "segmented_minmax": ("select l_returnflag, min(l_quantity) as mq, "
+                         "max(l_tax) as mt from lineitem "
+                         "group by l_returnflag order by l_returnflag"),
+    "sort_agg": ("select o_custkey, sum(o_totalprice) as s, "
+                 "count(*) as c from orders "
+                 "group by o_custkey order by o_custkey"),
+    "topk": ("select o_orderkey, o_totalprice from orders "
+             "order by o_totalprice desc limit 10"),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(_KERNEL_SQLS))
+def test_each_kernel_runs_through_the_engine(kernel, tpch_store):
+    """A SQL query through ``connect()`` runs each fused kernel, with the
+    rows of the generic path. The interpret-cost gate is bypassed: on
+    CPU it declines ``sort_agg`` and ``topk``, which a TPU runs."""
+    store, catalog = tpch_store
+    sql = _KERNEL_SQLS[kernel]
+    with connect(store, catalog, config=CFG) as session, \
+            lower.interpret_gate_disabled():
+        res = session.sql(sql)
+        assert any(p.kernel_runs.get(kernel)
+                   for p in res.stats.pipelines), \
+            [p.kernel_runs for p in res.stats.pipelines]
+        got = res.fetch(store)
+        with lower.disabled():
+            want = session.sql(sql).fetch(store)
+    assert sorted(got) == sorted(want)
+    for k in want:
+        np.testing.assert_allclose(
+            np.asarray(got[k], np.float64),
+            np.asarray(want[k], np.float64), rtol=1e-9, atol=1e-9,
+            err_msg=f"{kernel}.{k} (fused vs jnp)")
+
+
 def test_unmatched_plan_falls_back_cleanly(tpch_store, tpch_tables):
     sql = ("select l_orderkey, l_quantity from lineitem "
            "where l_quantity < 3")

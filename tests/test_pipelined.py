@@ -13,12 +13,14 @@ Covers the PR's invariants end to end:
   * pilot-scan selectivity probes and EXPLAIN ANALYZE surfacing.
 """
 
+import threading
 import time
 
 import numpy as np
 import pytest
 
 from repro.api import CoordinatorConfig, connect
+from repro.core.engine import QueryEngine
 from repro.core.platform import FaasPlatform, FaultPlan
 from repro.core.registry import ResultRegistry, partitions_ready
 from repro.data.catalog import Catalog, TableMeta
@@ -38,13 +40,18 @@ FACT_SCHEMA = [
 ]
 
 
+def _config(planner, *, pipelined, adaptive=False):
+    return CoordinatorConfig(planner=planner, use_result_cache=False,
+                             adaptive=adaptive, pipelined=pipelined)
+
+
 def _run(store, catalog, sql, *, pipelined, planner=PLANNER,
-         platform=None, adaptive=False):
-    cfg = CoordinatorConfig(planner=planner, use_result_cache=False,
-                            adaptive=adaptive, pipelined=pipelined)
+         platform=None, adaptive=False, registry=None):
+    cfg = _config(planner, pipelined=pipelined, adaptive=adaptive)
     kwargs = {"platform": platform} if platform is not None \
         else {"quota": 1000}
-    with connect(store, catalog, config=cfg, **kwargs) as session:
+    with connect(store, catalog, config=cfg, registry=registry,
+                 **kwargs) as session:
         res = session.submit(sql).result(timeout=300)
         cols = res.fetch(store)
     return cols, res.stats
@@ -101,6 +108,30 @@ def _make_fact(rows=4000, n_parts=4, groups=6, dim_rows=50, seed=0):
     return store, catalog
 
 
+class _AdmitBeforePublish(ResultRegistry):
+    """Publishes a producer's complete entry only once a consumer has
+    passed the admission gate of its partial stream. Without it the
+    partial path is a race: a producer that publishes before its
+    consumer's thread reaches the gate hands the consumer the complete
+    entry, and the consumer runs as behind a barrier."""
+
+    def __init__(self, store, consumed):
+        super().__init__(store)
+        self._admitted = {sem: threading.Event() for sem in consumed}
+
+    def await_source_ready(self, sem_hash, **kw):
+        entry = super().await_source_ready(sem_hash, **kw)
+        if kw.get("stream", "partial") == "partial" \
+                and sem_hash in self._admitted:
+            self._admitted[sem_hash].set()
+        return entry
+
+    def publish(self, sem_hash, **kw):
+        if sem_hash in self._admitted:
+            self._admitted[sem_hash].wait(timeout=60)
+        super().publish(sem_hash, **kw)
+
+
 # -- tentpole: pipelined ≡ barrier on TPC-H × every shuffle strategy ----------
 
 @pytest.mark.parametrize("strategy", ["direct", "combining",
@@ -115,8 +146,14 @@ def test_pipelined_matches_barrier_rows_tpch(tpch_store, qname,
                             exchange_strategy=strategy)
     barrier_cols, _ = _run(store, catalog, QUERIES[qname],
                            pipelined=False, planner=planner)
-    piped_cols, piped_stats = _run(store, catalog, QUERIES[qname],
-                                   pipelined=True, planner=planner)
+    plan = QueryEngine(store, catalog,
+                       config=_config(planner, pipelined=True)
+                       ).plan_sql(QUERIES[qname])
+    consumed = {plan.pipelines[d].sem_hash
+                for p in plan.pipelines.values() for d in p.deps}
+    piped_cols, piped_stats = _run(
+        store, catalog, QUERIES[qname], pipelined=True, planner=planner,
+        registry=_AdmitBeforePublish(store, consumed))
     _assert_same_rows(barrier_cols, piped_cols, f"{qname}/{strategy}")
     # multi-pipeline plans must actually have exercised partial-input
     # admission, not silently fallen back to barrier resolution

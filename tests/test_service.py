@@ -564,6 +564,23 @@ def test_fleet_cap_clamps_every_pipeline():
                for p in pipelines for a in p.adaptations)
 
 
+def test_feasible_deadline_is_never_missed():
+    """A TPC-H mix under a deadline its fleets can meet: the stage
+    budgets size every fleet, and no request misses."""
+    store, catalog = _fresh_db()
+    svc, session = _service(store, catalog, quota=8, tenants=(
+        TenantConfig("gold", weight=3.0, deadline_s=10.0),))
+    try:
+        handles = [svc.submit(QUERIES[q], tenant="gold")
+                   for q in ("q1", "q6", "q12", "q14")]
+        results = [h.result(timeout=300) for h in handles]
+        assert not any(r.deadline_missed for r in results)
+        assert svc.stats()["deadline_misses"] == 0
+    finally:
+        svc.close()
+        session.close()
+
+
 def test_deadline_miss_is_recorded_by_service():
     # fresh store: a result-cache hit would (correctly) meet any SLO
     store, catalog = _fresh_db()
